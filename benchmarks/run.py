@@ -40,6 +40,9 @@ def main() -> int:
     p.add_argument("--only", type=str, default="")
     args = p.parse_args()
 
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from benchmarks import (fig4_edgecut, fig5_vs_offline, fig6_dynamics,
                             fig7_imbalance, fig8_npartitions, fig9_scaling,
                             fig10_time, fig11_sweep_scaling,

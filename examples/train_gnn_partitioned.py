@@ -50,9 +50,9 @@ def main():
     p.add_argument("--hidden", type=int, default=32)
     args = p.parse_args()
 
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh
     n_dev = len(jax.devices())
-    mesh = make_mesh_compat((n_dev,), ("data",))
+    mesh = make_mesh((n_dev,), ("data",))
     g = make_graph("mesh", args.nodes, 3 * args.nodes, seed=0)
     rng = np.random.default_rng(0)
     feats = rng.standard_normal((g.n, args.hidden)).astype(np.float32)

@@ -86,8 +86,8 @@ from repro.core.state import (
     recount_cut_matrix, shrink_state, state_bytes, state_metrics,
 )
 from repro.core.sharded_state import (
-    gather_state, pad_rows, per_device_state_bytes, shard_state,
-    unshard_state,
+    gather_state, init_sharded_state, pad_rows, per_device_state_bytes,
+    shard_state, unshard_state,
 )
 from repro.core.transition import EventTrace
 from repro.core.metrics import load_imbalance, normalized_load_imbalance
@@ -339,15 +339,18 @@ class Partitioner:
         self._fallback_windows = 0
         self._sharded = bool(sharded)
         self._mesh = None
-        self._state = init_state(int(n or 1), int(max_deg or 1), cfg.k_max,
-                                 cfg.k_init, seed)
         if self._sharded:
             self._mesh = _resolve_vertices_mesh(shard_devices)
             # semantic geometry: the tier a dense session would sit at —
             # what the knobs (LDG capacity) and checkpoints see; the
             # physical row count is padded to a multiple of the mesh
-            self._sem_geom = geometry_of(self._state)
-            self._state = shard_state(self._state, self._mesh)
+            self._sem_geom = Geometry(int(n or 1), int(max_deg or 1),
+                                      cfg.k_max)
+            self._state = init_sharded_state(
+                *self._sem_geom, cfg.k_init, seed, self._mesh)
+        else:
+            self._state = init_state(int(n or 1), int(max_deg or 1),
+                                     cfg.k_max, cfg.k_init, seed)
         self._regeometries = 0
         self._shrinks = 0
         self._compactions = 0

@@ -27,7 +27,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.geometry import Geometry, geometry_of
-from repro.core.state import PartitionState, grow_state
+from repro.core.state import PartitionState, grow_state, init_state
 
 
 def n_shards(mesh: jax.sharding.Mesh) -> int:
@@ -75,6 +75,19 @@ def shard_state(state: PartitionState,
     if target != g.n:
         state = grow_state(state, Geometry(target, g.max_deg, g.k_max))
     return jax.device_put(state, state_shardings(mesh))
+
+
+def init_sharded_state(n: int, max_deg: int, k_max: int, k_init: int,
+                       seed: int, mesh: jax.sharding.Mesh) -> PartitionState:
+    """``init_state`` built directly in its vertices-mesh layout, rows
+    padded to a multiple of the shard count. Each device allocates only
+    its own row blocks, so a state larger than one device's memory can
+    be created at all (``shard_state(init_state(...))`` would first
+    materialize the whole state on one device)."""
+    n_pad = pad_rows(n, n_shards(mesh))
+    build = jax.jit(init_state, static_argnums=(0, 1, 2, 3, 4),
+                    out_shardings=state_shardings(mesh))
+    return build(n_pad, max_deg, k_max, k_init, seed)
 
 
 def gather_state(state: PartitionState,

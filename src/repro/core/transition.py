@@ -144,21 +144,104 @@ def neighbor_stats(state: PartitionState, row: jax.Array):
     return scores, deg, nb_present, safe_row
 
 
+# ---------------------------------------------------------------------------
+# index primitives in mask form
+# ---------------------------------------------------------------------------
+#
+# The fused Pallas chooser runs the policy and scaling bodies below inside
+# a Mosaic kernel, which lowers neither argmax/argmin over integers nor a
+# dynamic index, gather or scatter on a vector value. Every such access is
+# therefore written as a compare against an iota followed by a select and
+# an integer reduction. On integers this is exact, so the XLA engines that
+# share these bodies compute the same values as before. K-vectors may be
+# 1-D ``(K,)`` (the XLA engines) or ``(1, K)`` rows (the kernel): every
+# helper works along the last axis.
+
+def lane_iota(x: jax.Array) -> jax.Array:
+    """int32 index along the last axis of ``x``, in ``x``'s shape."""
+    return jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+
+
+def take_at(x: jax.Array, i) -> jax.Array:
+    """``x[..., i]`` of a K-vector as a scalar (``i`` in range)."""
+    if x.dtype == jnp.bool_:
+        return take_at(x.astype(jnp.int32), i) != 0
+    return jnp.sum(jnp.where(lane_iota(x) == i, x, jnp.zeros((), x.dtype)))
+
+
+def add_at(x: jax.Array, i, val) -> jax.Array:
+    """``x.at[..., i].add(val)`` of a K-vector."""
+    return x + jnp.where(lane_iota(x) == i, val, jnp.zeros((), x.dtype))
+
+
+def set_at(x: jax.Array, i, val) -> jax.Array:
+    """``x.at[..., i].set(val)`` of a K-vector."""
+    return jnp.where(lane_iota(x) == i, val, x)
+
+
+def first_index(mask: jax.Array) -> jax.Array:
+    """Index of the first True along the last axis; the axis size if none."""
+    k = mask.shape[-1]
+    return jnp.min(jnp.where(mask, lane_iota(mask), k)).astype(jnp.int32)
+
+
+def _square_iotas(k: int):
+    return (jax.lax.broadcasted_iota(jnp.int32, (k, k), 0),
+            jax.lax.broadcasted_iota(jnp.int32, (k, k), 1))
+
+
+def row_to_col(row: jax.Array) -> jax.Array:
+    """A K-vector (``(K,)`` or ``(1, K)``) as a ``(K, 1)`` column, through
+    the diagonal of a ``(K, K)`` select — no transpose or reshape."""
+    ri, ci = _square_iotas(row.shape[-1])
+    return jnp.sum(jnp.where(ri == ci, row, jnp.zeros((), row.dtype)),
+                   axis=1, keepdims=True)
+
+
+def matrix_at(m: jax.Array, i, j) -> jax.Array:
+    """``m[i, j]`` of a ``(K, K)`` matrix as a scalar."""
+    ri, ci = _square_iotas(m.shape[0])
+    return jnp.sum(jnp.where((ri == i) & (ci == j), m, 0))
+
+
+def matrix_add_at(m: jax.Array, i, j, val) -> jax.Array:
+    """``m.at[i, j].add(val)`` of a ``(K, K)`` matrix."""
+    ri, ci = _square_iotas(m.shape[0])
+    return m + jnp.where((ri == i) & (ci == j), val, 0)
+
+
+def matrix_add_cross(m: jax.Array, p, vec) -> jax.Array:
+    """``m.at[p, :].add(vec).at[:, p].add(vec)`` of a ``(K, K)`` matrix —
+    the cut-matrix update every transition core makes."""
+    ri, ci = _square_iotas(m.shape[0])
+    return (m + jnp.where(ri == p, vec, 0)
+            + jnp.where(ci == p, row_to_col(vec), 0))
+
+
 def nth_active(active: jax.Array, i: jax.Array) -> jax.Array:
     """Index of the i-th active partition, with i taken modulo the active
     count. Callers draw i in [0, num_partitions); clamping keeps the result
     an *active* index even if num_partitions ever drifts from
-    popcount(active) (an unclamped argmax over an all-False mask would
-    silently return slot 0, possibly inactive). All-inactive still yields 0
-    — there is no valid answer in that state."""
-    cnt = jnp.sum(active, dtype=jnp.int32)
+    popcount(active). All-inactive yields 0 — there is no valid answer in
+    that state.
+
+    The (i+1)-th active index equals the number of positions whose
+    inclusive active prefix count is at most i; the prefix counts come from
+    a triangular masked sum."""
+    a = active.astype(jnp.int32)
+    cnt = jnp.sum(a)
     i = jnp.mod(i, jnp.maximum(cnt, 1))
-    cum = jnp.cumsum(active.astype(jnp.int32)) - 1
-    return jnp.argmax((cum == i) & active).astype(jnp.int32)
+    ri, ci = _square_iotas(a.shape[-1])
+    incl = jnp.sum(jnp.where(ci <= ri, a, 0), axis=1, keepdims=True)
+    p = jnp.sum((incl <= i).astype(jnp.int32))
+    return jnp.where(cnt > 0, p, 0)
 
 
 def masked_argmin(x: jax.Array, mask: jax.Array) -> jax.Array:
-    return jnp.argmin(jnp.where(mask, x, _BIG)).astype(jnp.int32)
+    """First index of the least ``x`` among ``mask`` (0 if none), as
+    ``argmin`` would return it."""
+    v = jnp.where(mask, x, _BIG)
+    return first_index(v == jnp.min(v))
 
 
 def load_stats(state):
@@ -401,11 +484,12 @@ def scale_out(state, kn: Knobs):
     p = jnp.maximum(state.num_partitions.astype(jnp.float32), 1.0)
     adding_threshold = state.total_edges.astype(jnp.float32) / p
     want = kn.max_cap <= adding_threshold
-    slot_free = ~jnp.all(state.active)
+    inactive = ~state.active
+    slot_free = jnp.sum(inactive.astype(jnp.int32)) > 0
     do = want & slot_free
-    slot = jnp.argmax(~state.active).astype(jnp.int32)  # first inactive slot
+    slot = first_index(inactive)                          # first inactive slot
     return state._replace(
-        active=state.active.at[slot].set(jnp.where(do, True, state.active[slot])),
+        active=state.active | ((lane_iota(inactive) == slot) & do),
         num_partitions=state.num_partitions + do.astype(jnp.int32),
         scale_events=state.scale_events + do.astype(jnp.int32),
         denied_scaleout=state.denied_scaleout + (want & ~slot_free).astype(jnp.int32),
@@ -434,23 +518,23 @@ def merge_cut_matrix(cut_matrix: jax.Array, src, dst) -> jax.Array:
     map src→dst. Preserves symmetry, row sums (= merged edge_load), and
     the off-diagonal half-sum dropping by exactly M[src, dst] (= the
     merged cut delta)."""
-    row = cut_matrix[src, :]
-    ss = cut_matrix[src, src]
-    cm = (cut_matrix.at[dst, :].add(row).at[:, dst].add(row)
-          .at[dst, dst].add(ss))
-    return cm.at[src, :].set(0).at[:, src].set(0)
+    ri, ci = _square_iotas(cut_matrix.shape[0])
+    row = jnp.sum(jnp.where(ri == src, cut_matrix, 0), axis=0, keepdims=True)
+    cm = matrix_add_cross(cut_matrix, dst, row)
+    cm = matrix_add_at(cm, dst, dst, matrix_at(cut_matrix, src, src))
+    return jnp.where((ri == src) | (ci == src), 0, cm)
 
 
 def scale_in_trigger(small, kn: Knobs):
     """Eqs. 6–8 trigger: (src, dst, do). `small` is any state carrying
     active/edge_load/num_partitions — shared with the windowed journal."""
     under = small.active & (small.edge_load.astype(jnp.float32) < kn.scale_in_l)
-    n_under = jnp.sum(under, dtype=jnp.int32)
+    n_under = jnp.sum(under.astype(jnp.int32))
     src = masked_argmin(small.edge_load, small.active)
-    mask2 = small.active.at[src].set(False)
+    mask2 = small.active & (lane_iota(small.active) != src)
     dst = masked_argmin(small.edge_load, mask2)
-    fits = (small.edge_load[src] + small.edge_load[dst]).astype(
-        jnp.float32) <= kn.scale_in_dest
+    fits = (take_at(small.edge_load, src) + take_at(small.edge_load, dst)
+            ).astype(jnp.float32) <= kn.scale_in_dest
     do = (small.num_partitions > 1) & (n_under >= 2) & fits
     return src, dst, do
 

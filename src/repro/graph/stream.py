@@ -386,6 +386,124 @@ def poisson_arrivals(
     return bounds, np.cumsum(gaps)
 
 
+def powerlaw_churn(
+    n: int,
+    num_events: int,
+    *,
+    max_deg: int = 192,
+    exponent: float = 2.3,
+    min_deg: int = 2,
+    vertex_del_frac: float = 0.15,
+    edge_del_frac: float = 0.15,
+    warmup: int = 512,
+    seed: int = 0,
+) -> VertexStream:
+    """Seeded power-law churn stream over a large vertex universe, built
+    in bulk (no per-vertex Python graph construction, so ``n`` in the
+    millions costs nothing beyond the ids the stream touches).
+
+    The first ``warmup`` events are adds; after that each event is an
+    add, a vertex delete or an edge delete with the given shares. Added
+    vertices take distinct ids drawn uniformly from ``[0, n)``. Arrival
+    ``j`` links to ``d_j`` earlier arrivals, ``d_j`` from a Pareto tail of
+    the given ``exponent`` starting at ``min_deg``; the partner index is
+    ``floor(j * u**2)`` for uniform ``u``, so early arrivals collect
+    links and become hubs. The undirected graph is fixed up front and
+    both endpoints list every edge (the generator discipline of the
+    adversarial streams below); an edge that would overflow ``max_deg``
+    at either endpoint is dropped from both, counted in
+    ``truncated_nbrs``. Deletes name only present vertices and live
+    edges between present vertices; nothing is re-added.
+    """
+    add_frac = 1.0 - vertex_del_frac - edge_del_frac
+    if add_frac <= 0 or min(vertex_del_frac, edge_del_frac) < 0:
+        raise ValueError(
+            f"vertex_del_frac={vertex_del_frac} and edge_del_frac="
+            f"{edge_del_frac} must be >= 0 and leave a positive add share")
+    rng = np.random.default_rng(seed)
+    kinds = rng.choice(
+        np.asarray([EVENT_ADD, EVENT_DEL_VERTEX, EVENT_DEL_EDGE], np.int32),
+        size=num_events, p=[add_frac, vertex_del_frac, edge_del_frac])
+    kinds[:warmup] = EVENT_ADD
+    n_add = int(np.sum(kinds == EVENT_ADD))
+    if n_add > n:
+        raise ValueError(f"{n_add} adds need n >= {n_add} distinct ids, "
+                         f"got n={n}")
+    ids = rng.choice(n, size=n_add, replace=False).astype(np.int32)
+
+    # --- the static graph, in arrival-index space -----------------------
+    deg = np.minimum(min_deg * (1.0 - rng.random(n_add))
+                     ** (-1.0 / (exponent - 1.0)), max_deg).astype(np.int64)
+    j = np.arange(n_add, dtype=np.int64)[:, None]
+    pick = (j * rng.random((n_add, max_deg)) ** 2).astype(np.int64)
+    links = (np.arange(max_deg)[None, :] < deg[:, None]) & (j > 0)
+    key = np.unique((np.broadcast_to(j, links.shape) * n_add + pick)[links])
+    a, b = np.divmod(key, n_add)                      # a > b, unique pairs
+
+    def ranks(src, dst):
+        """Position of each (src, dst) entry within src's row, rows
+        ordered by partner arrival."""
+        order = np.lexsort((dst, src))
+        first = np.searchsorted(src[order], src[order], side="left")
+        r = np.empty_like(src)
+        r[order] = np.arange(src.size) - first
+        return r
+
+    e = a.size
+    r = ranks(np.concatenate([a, b]), np.concatenate([b, a]))
+    keep = (r[:e] < max_deg) & (r[e:] < max_deg)
+    truncated = 2 * int(e - np.sum(keep))
+    a, b = a[keep], b[keep]
+    src, dst = np.concatenate([a, b]), np.concatenate([b, a])
+    rows = np.full((n_add, max_deg), -1, np.int64)     # arrival indices
+    rows[src, ranks(src, dst)] = dst
+
+    # --- the event sequence ----------------------------------------------
+    etype = np.empty(num_events, np.int32)
+    vertex = np.empty(num_events, np.int32)
+    nbrs = np.full((num_events, max_deg), -1, np.int32)
+    present = np.zeros(n_add, bool)
+    live: list[int] = []                 # present arrival indices
+    slot = np.full(n_add, -1, np.int64)  # position in `live`
+    dead: set[tuple[int, int]] = set()
+    nxt = 0
+
+    def drop(x: int) -> None:
+        i, last = slot[x], live[-1]
+        live[i], slot[last] = last, i
+        live.pop()
+        slot[x] = -1
+        present[x] = False
+
+    for t, kind in enumerate(kinds):
+        if kind == EVENT_DEL_EDGE:
+            v = live[int(rng.integers(len(live)))]
+            cand = rows[v][rows[v] >= 0]
+            cand = [int(u) for u in cand[present[cand]]
+                    if (min(v, int(u)), max(v, int(u))) not in dead]
+            if cand:
+                u = cand[int(rng.integers(len(cand)))]
+                dead.add((min(v, u), max(v, u)))
+                etype[t], vertex[t], nbrs[t, 0] = EVENT_DEL_EDGE, ids[v], ids[u]
+                continue
+            kind = EVENT_DEL_VERTEX          # v has no live edge left
+        if kind == EVENT_DEL_VERTEX and live:
+            v = live[int(rng.integers(len(live)))]
+            drop(v)
+            etype[t], vertex[t] = EVENT_DEL_VERTEX, ids[v]
+            continue
+        etype[t], vertex[t] = EVENT_ADD, ids[nxt]
+        row = rows[nxt]
+        nbrs[t] = np.where(row >= 0, ids[np.maximum(row, 0)], -1)
+        present[nxt] = True
+        slot[nxt] = len(live)
+        live.append(nxt)
+        nxt += 1
+
+    return VertexStream(etype=etype, vertex=vertex, nbrs=nbrs, n=n,
+                        intervals=(num_events,), truncated_nbrs=truncated)
+
+
 def pad_stream(s: VertexStream, multiple: int) -> VertexStream:
     """Pad the event tensor length to a multiple (for fixed-window engines)."""
     t = s.num_events

@@ -2,23 +2,19 @@
 repro kernel must agree on:
 
 * **interpret-mode policy** (``default_interpret``): kernels run in Pallas
-  interpret mode everywhere except a real TPU backend, so the same
+  interpret mode exactly when the backend is not a TPU, so the same
   ``use_kernel=True`` call sites exercise the kernel logic bit-identically
-  in CPU CI and compile to real Mosaic on TPU. ``REPRO_PALLAS_INTERPRET``
-  overrides for debugging (``=1`` forces interpret on TPU, ``=0`` forces a
-  real compile elsewhere — which will fail off-TPU; that is the point of
-  the override).
+  in CPU CI and compile to real Mosaic on TPU.
 
-* **label-histogram masking** (``label_histogram``): affinity scoring is a
-  compare+reduce one-hot histogram over neighbour labels where ``-1``
-  means "no neighbour here" (absent vertex, padded slot, or padded tile)
-  and matches no partition id. Both the batched committed-scores kernel
-  (``partition_affinity``) and the fused window chooser score through this
-  helper, so their tiling/masking semantics cannot drift.
+* **label-histogram masking** (``label_histogram``, ``row_histogram``):
+  affinity scoring is a compare+reduce one-hot histogram over neighbour
+  labels where ``-1`` means "no neighbour here" (absent vertex, padded
+  slot, or padded tile) and matches no partition id. The batched
+  committed-scores kernel (``partition_affinity``) scores through the
+  first, the fused window chooser's slot loop through the second; both
+  share the masking convention, so their semantics cannot drift.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
@@ -28,20 +24,10 @@ import jax.numpy as jnp
 # any geometry; see docs/ARCHITECTURE.md "Kernels").
 TILE = (8, 128)
 
-_ENV = "REPRO_PALLAS_INTERPRET"
-
-
 def default_interpret() -> bool:
-    """True ⇔ Pallas kernels should run in interpret mode.
-
-    Derived from the backend (`jax.default_backend() != "tpu"`) so the one
-    ``use_kernel=True`` flag means "real kernel on TPU, interpreted
-    elsewhere"; the ``REPRO_PALLAS_INTERPRET`` env var overrides both ways
-    for debugging.
-    """
-    override = os.environ.get(_ENV)
-    if override is not None:
-        return override.strip().lower() not in ("0", "false", "no", "off")
+    """True ⇔ Pallas kernels should run in interpret mode: the backend is
+    not a TPU. The one ``use_kernel=True`` flag thus means "real kernel on
+    TPU, interpreted elsewhere"."""
     return jax.default_backend() != "tpu"
 
 
@@ -63,4 +49,19 @@ def label_histogram(labels: jax.Array, k_max: int):
     onehot = (labels[..., None] == ks).astype(jnp.int32)
     scores = jnp.sum(onehot, axis=-2)
     deg = jnp.sum((labels >= 0).astype(jnp.int32), axis=-1, keepdims=True)
+    return scores, deg
+
+
+def row_histogram(labels: jax.Array, k_max: int):
+    """(1, D) int32 labels → ((1, K) scores, scalar degree), in the shapes
+    a Mosaic kernel lowers: a (K, D) compare against a sublane iota, a
+    lane reduction to a (K, 1) column, and the column turned into a row
+    through the diagonal of a (K, K) select. Same ``-1`` masking and the
+    same exact integer counts as ``label_histogram``."""
+    ks = jax.lax.broadcasted_iota(jnp.int32, (k_max, labels.shape[-1]), 0)
+    col = jnp.sum((labels == ks).astype(jnp.int32), axis=1, keepdims=True)
+    ri = jax.lax.broadcasted_iota(jnp.int32, (k_max, k_max), 0)
+    ci = jax.lax.broadcasted_iota(jnp.int32, (k_max, k_max), 1)
+    scores = jnp.sum(jnp.where(ri == ci, col, 0), axis=0, keepdims=True)
+    deg = jnp.sum((labels >= 0).astype(jnp.int32))
     return scores, deg

@@ -32,27 +32,33 @@ def fused_window_choose_ref(ev, src_lbl, touch, rand_tab, active, edge_load,
     slot_step = make_slot_step(k_max=k_max, n=n, choose=choose,
                                autoscaling=autoscaling, dynamic=dynamic)
 
+    def row(x):
+        return x.reshape(1, k_max)
+
     small0 = SmallState(
-        active=active != 0, edge_load=edge_load, vertex_count=vertex_count,
+        active=row(active != 0), edge_load=row(edge_load),
+        vertex_count=row(vertex_count),
         num_partitions=scalars[SCAL_NP], total_edges=scalars[SCAL_TOTAL],
         cut_edges=scalars[SCAL_CUT], denied_scaleout=scalars[SCAL_DENIED],
         scale_events=scalars[SCAL_SCALE], cut_matrix=cut_matrix)
-    w_label0 = jnp.full((w,), -1, jnp.int32)
-    remap0 = jnp.arange(k_max, dtype=jnp.int32)
+    w_label0 = jnp.full((w, 1), -1, jnp.int32)
+    remap0 = jnp.arange(k_max, dtype=jnp.int32)[:, None]
 
     def body(carry, xs):
         small, w_label, remap = carry
         i, ev_i, src_i, touch_i, rand_i = xs
         small, w_label, remap, p = slot_step(
-            small, w_label, remap, kn, do_scale, i, ev_i, src_i, touch_i,
-            rand_i)
+            small, w_label, remap, kn, do_scale, i,
+            [ev_i[c] for c in range(ev_i.shape[0])],
+            src_i[None, :], touch_i[None, :], rand_i[None, :])
         return (small, w_label, remap), p
 
     (small, w_label, remap), psel = jax.lax.scan(
         body, (small0, w_label0, remap0),
         (jnp.arange(w, dtype=jnp.int32), ev, src_lbl, touch, rand_tab))
-    return (w_label, psel, remap, small.active.astype(jnp.int32),
-            jnp.stack([small.edge_load, small.vertex_count]),
+    return (w_label[:, 0], psel, remap[:, 0],
+            small.active[0].astype(jnp.int32),
+            jnp.concatenate([small.edge_load, small.vertex_count]),
             small.cut_matrix,
             jnp.stack([small.num_partitions, small.total_edges,
                        small.cut_edges, small.denied_scaleout,

@@ -55,8 +55,7 @@ def partition_affinity(
     """(scores (W, K), deg (W,)) from neighbour partition labels (W, D).
 
     ``interpret=None`` defers to ``repro.kernels.common.default_interpret``
-    — real Mosaic compile on a TPU backend, interpret mode elsewhere,
-    ``REPRO_PALLAS_INTERPRET`` overriding for debugging.
+    — real Mosaic compile on a TPU backend, interpret mode elsewhere.
     """
     interpret = resolve_interpret(interpret)
     w, d = labels.shape

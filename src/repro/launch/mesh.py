@@ -14,26 +14,21 @@ from __future__ import annotations
 import jax
 
 
-def make_mesh_compat(shape, axes) -> jax.sharding.Mesh:
-    """jax.make_mesh across jax versions: axis_types landed after 0.4.x."""
-    try:
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
-        )
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with every axis in Auto (GSPMD) mode."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
     """Small mesh over whatever devices exist (tests / examples)."""
-    return make_mesh_compat((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def make_lane_mesh(n_devices: int | None = None) -> jax.sharding.Mesh:
@@ -45,7 +40,7 @@ def make_lane_mesh(n_devices: int | None = None) -> jax.sharding.Mesh:
     """
     if n_devices is None:
         n_devices = jax.device_count()
-    return make_mesh_compat((n_devices,), ("lanes",))
+    return make_mesh((n_devices,), ("lanes",))
 
 
 def make_vertices_mesh(n_devices: int | None = None,
@@ -106,23 +101,6 @@ def make_grid_mesh(n_lanes: int, n_vertices: int,
             "lane-sharded and vertex-sharded work as separate sweeps")
     grid = np.asarray(devices[:need]).reshape(n_lanes, n_vertices)
     return jax.sharding.Mesh(grid, ("lanes", "vertices"))
-
-
-def shard_map_compat(f, mesh, in_specs, out_specs, check_rep=True):
-    """jax.shard_map across jax versions (experimental until ~0.6).
-
-    ``check_rep=False`` disables the replication checker — required when
-    the mapped body contains a ``pallas_call`` (the sweep runtime's fused
-    chooser lanes), which has no replication rule; lanes are
-    embarrassingly parallel so the check is vacuous there anyway.
-    """
-    kw = {} if check_rep else {"check_rep": False}
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     **kw)
 
 
 def mesh_devices(mesh: jax.sharding.Mesh) -> int:

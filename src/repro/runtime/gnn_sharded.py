@@ -12,7 +12,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.graph.halo import HaloSpec
 from repro.models.gnn import common as C
@@ -48,7 +47,7 @@ def make_sharded_aggregate(mesh, spec: HaloSpec, axis: str = "data"):
         def shard_body(x, pi, hm, sn, rc):
             return body(x[0], pi[0], hm[0], sn[0], rc[0])[None]
 
-        return shard_map(
+        return jax.shard_map(
             shard_body, mesh=mesh,
             in_specs=(P(axis), P(axis), P(axis), P(axis), P(axis)),
             out_specs=P(axis),

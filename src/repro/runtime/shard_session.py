@@ -68,16 +68,16 @@ from repro.core import transition as tx
 from repro.core.config import EngineConfig
 from repro.core.geometry import Geometry, resolve_geometry
 from repro.core.sharded_state import (
-    shard_state, state_specs, unshard_state,
+    init_sharded_state, state_specs, unshard_state,
 )
-from repro.core.state import PartitionState, init_state
+from repro.core.state import PartitionState
 from repro.graph.stream import (
     EVENT_ADD, EVENT_DEL_EDGE, EVENT_DEL_VERTEX, EVENT_PAD,
     VertexStream, normalize_rows, pad_stream,
 )
 from repro.kernels.fused_chooser import fused_chooser as fk
 from repro.kernels.fused_chooser.ref import fused_window_choose_ref
-from repro.launch.mesh import make_vertices_mesh, shard_map_compat
+from repro.launch.mesh import make_vertices_mesh
 
 AXIS = "vertices"
 
@@ -252,10 +252,10 @@ def sharded_stream_fn(mesh: jax.sharding.Mesh, *, n_sem: int, policy: str,
         return state
 
     specs = state_specs()
-    fn = shard_map_compat(
-        body_stream, mesh,
+    fn = jax.shard_map(
+        body_stream, mesh=mesh,
         in_specs=(specs, P(), P(), P(), P()),
-        out_specs=specs, check_rep=False)
+        out_specs=specs, check_vma=False)
     return jax.jit(fn, donate_argnums=(0,) if donate else ())
 
 
@@ -279,8 +279,8 @@ def run_stream_sharded(
     geom = resolve_geometry(stream, cfg, geometry)
     if mesh is None:
         mesh = make_vertices_mesh(devices=devices)
-    state = shard_state(
-        init_state(geom.n, geom.max_deg, geom.k_max, cfg.k_init, seed), mesh)
+    state = init_sharded_state(geom.n, geom.max_deg, geom.k_max, cfg.k_init,
+                               seed, mesh)
     s = pad_stream(stream, window)
     ets = jnp.asarray(s.etype)
     vs = jnp.asarray(s.vertex)

@@ -61,7 +61,7 @@ from repro.core.state import PartitionState, init_state
 from repro.core.windowed import sweep_window_mixed
 from repro.graph.stream import EVENT_PAD, VertexStream, normalize_rows
 from repro.kernels.fused_chooser.ops import sweep_window_mixed_fused
-from repro.launch.mesh import make_lane_mesh, shard_map_compat
+from repro.launch.mesh import make_lane_mesh
 
 
 class SweepRun(NamedTuple):
@@ -173,13 +173,13 @@ def _sharded_kernel(kind: str, n_devices: int, balance_guard: str,
     if kind in ("windowed", "windowed_fused"):
         kw["window"] = window
     base = functools.partial(_KERNELS[kind], **kw)
-    return jax.jit(shard_map_compat(
-        base, mesh,
+    return jax.jit(jax.shard_map(
+        base, mesh=mesh,
         in_specs=(lanes,) * 4 + (stream_spec,) * 3 + (P(),),
         out_specs=lanes,
-        # pallas_call has no replication rule; lanes emit no collectives,
+        # pallas_call has no varying-axes rule; lanes emit no collectives,
         # so the checker is vacuous for every kind
-        check_rep=kind != "windowed_fused"),
+        check_vma=kind != "windowed_fused"),
         donate_argnums=(0,))
 
 

@@ -86,8 +86,8 @@ st, _ = run_stream(s, policy="sdp",
                    cfg=EngineConfig(k_max=4, k_init=4, autoscale=False))
 assign = np.array(st.assignment); assign[assign < 0] = 0
 spec = build_halo_spec(g, assign, 4)
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((4,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4,), ("data",))
 x = np.random.default_rng(0).standard_normal((g.n, 8)).astype(np.float32)
 blocks = scatter_nodes(spec, x)
 agg = make_sharded_aggregate(mesh, spec)
@@ -193,8 +193,8 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax
 from repro.launch.steps import build_step
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 b = build_step("pna", "molecule", mesh)
 with mesh:
     co = jax.jit(b.fn, in_shardings=b.in_shardings,

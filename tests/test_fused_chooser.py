@@ -211,7 +211,7 @@ def test_sweep_kernel_lanes_parity():
 
 
 def test_sweep_kernel_shared_stream_sharded():
-    """Shared-stream broadcast + shard_map path (check_rep off for the
+    """Shared-stream broadcast + shard_map path (check_vma off for the
     pallas_call) stays bit-identical, even forced onto one device."""
     s = _churn_stream(seed=2)
     cfg = _cfg_for("sdp", autoscale=False, k_init=3)
@@ -247,21 +247,10 @@ def test_rand_index_table_matches_per_event_randint():
 
 
 def test_interpret_resolution():
-    """One definition site: default follows the backend, the env var
-    overrides, and an explicit argument beats both."""
+    """One definition site: default follows the backend, and an explicit
+    argument beats it."""
     backend_default = jax.default_backend() != "tpu"
     assert kcommon.default_interpret() is backend_default
     assert kcommon.resolve_interpret(None) is backend_default
     assert kcommon.resolve_interpret(True) is True
     assert kcommon.resolve_interpret(False) is False
-
-
-def test_interpret_env_override(monkeypatch):
-    monkeypatch.setenv(kcommon._ENV, "0")
-    assert kcommon.default_interpret() is False
-    monkeypatch.setenv(kcommon._ENV, "1")
-    assert kcommon.default_interpret() is True
-    monkeypatch.setenv(kcommon._ENV, "false")
-    assert kcommon.default_interpret() is False
-    monkeypatch.delenv(kcommon._ENV)
-    assert kcommon.default_interpret() is (jax.default_backend() != "tpu")

@@ -87,16 +87,11 @@ def test_error_feedback_unbiased_over_time():
     g = {"w": jnp.full((64,), 0.01)}   # tiny values → large relative quant
     err = init_error_feedback(g)
     from jax.sharding import PartitionSpec as P
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((1,), ("dp",))
-
-    if hasattr(jax, "shard_map"):
-        shard_map = jax.shard_map
-    else:  # older jax keeps it in experimental
-        from jax.experimental.shard_map import shard_map
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("dp",))
 
     def run(err):
-        f = shard_map(
+        f = jax.shard_map(
             lambda gg, ee: compressed_allreduce_grads(gg, ee, "dp"),
             mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()))
         return f(g, err)

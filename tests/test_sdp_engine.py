@@ -198,3 +198,89 @@ def test_chunked_run_equals_single_shot():
     np.testing.assert_array_equal(np.asarray(a.assignment),
                                   np.asarray(b.assignment))
     assert int(a.cut_edges) == int(b.cut_edges)
+
+
+def test_mask_form_index_helpers_match_numpy():
+    """The iota/select/reduce forms the fused kernel lowers equal plain
+    indexing, argmin and cumsum — on 1-D vectors (the XLA engines) and on
+    (1, K) rows (the kernel) alike."""
+    import jax.numpy as jnp
+    from repro.core import transition as tx
+    rng = np.random.default_rng(0)
+    for _ in range(40):
+        k = int(rng.integers(1, 17))
+        x = rng.integers(0, 5, k).astype(np.int32)
+        mask = rng.random(k) < 0.5
+        i = int(rng.integers(0, k))
+        for shape in ((k,), (1, k)):
+            xj, mj = jnp.asarray(x).reshape(shape), jnp.asarray(mask).reshape(shape)
+            assert int(tx.take_at(xj, i)) == x[i]
+            assert bool(tx.take_at(mj, i)) == mask[i]
+            np.testing.assert_array_equal(
+                np.asarray(tx.add_at(xj, i, 7)).ravel(),
+                x + 7 * (np.arange(k) == i))
+            want = int(np.argmin(np.where(mask, x, 2**30)))
+            assert int(tx.masked_argmin(xj, mj)) == want
+            cum = np.cumsum(mask) - 1
+            want = int(np.argmax((cum == i % max(mask.sum(), 1)) & mask))
+            assert int(tx.nth_active(mj, jnp.int32(i))) == want
+            np.testing.assert_array_equal(
+                np.asarray(tx.row_to_col(xj)), x[:, None])
+        m = rng.integers(0, 9, (k, k)).astype(np.int32)
+        j = int(rng.integers(0, k))
+        want = m.copy()
+        want[i, :] += x
+        want[:, i] += x
+        np.testing.assert_array_equal(
+            np.asarray(tx.matrix_add_cross(jnp.asarray(m), i, jnp.asarray(x))),
+            want)
+        assert int(tx.matrix_at(jnp.asarray(m), i, j)) == m[i, j]
+
+
+def test_powerlaw_churn_discipline():
+    """Seeded and reproducible; ids spread over the universe; rows list
+    every edge at both endpoints; deletes name present vertices and live
+    edges between present vertices."""
+    s = gstream.powerlaw_churn(1 << 20, 3000, max_deg=48, warmup=200, seed=4)
+    t = gstream.powerlaw_churn(1 << 20, 3000, max_deg=48, warmup=200, seed=4)
+    np.testing.assert_array_equal(s.nbrs, t.nbrs)
+    assert set(np.unique(s.etype)) == {gstream.EVENT_ADD,
+                                       gstream.EVENT_DEL_VERTEX,
+                                       gstream.EVENT_DEL_EDGE}
+    assert s.vertex.max() > (1 << 19)          # not packed at the bottom
+    rows = {int(v): set(r[r >= 0].tolist())
+            for v, r, e in zip(s.vertex, s.nbrs, s.etype)
+            if e == gstream.EVENT_ADD}
+    for v, nb in rows.items():
+        assert v not in nb and all(v in rows[u] for u in nb)
+    present, dead = set(), set()
+    for e, v, r in zip(s.etype, s.vertex, s.nbrs):
+        v = int(v)
+        if e == gstream.EVENT_ADD:
+            assert v not in present
+            present.add(v)
+        elif e == gstream.EVENT_DEL_VERTEX:
+            present.remove(v)
+        else:
+            u = int(r[0])
+            edge = (min(u, v), max(u, v))
+            assert u in present and v in present and u in rows[v]
+            assert edge not in dead
+            dead.add(edge)
+
+
+@pytest.mark.parametrize("use_kernel", [False, True])
+def test_engine_matches_oracle_powerlaw_churn(use_kernel):
+    """Power-law churn with hubs at the row cap and autoscale thrashing K
+    up and down: the windowed engine (XLA or the fused kernel) and the
+    scan agree with the oracle."""
+    from repro.core import run_stream_windowed
+    s = gstream.powerlaw_churn(1 << 12, 1500, max_deg=32, warmup=200, seed=6)
+    cfg = EngineConfig(k_max=8, k_init=1, max_cap=300)
+    ref = run_reference(s, policy="sdp", cfg=cfg, seed=2)
+    assert ref.scale_events > 2
+    scan, _ = run_stream(s, policy="sdp", cfg=cfg, seed=2)
+    _assert_match(scan, ref)
+    win = run_stream_windowed(s, policy="sdp", cfg=cfg, seed=2, window=64,
+                              use_kernel=use_kernel)
+    _assert_match(win, ref)

@@ -98,8 +98,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_arch
 from repro.models import recsys as RS
 
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 cfg = dataclasses.replace(get_arch("two-tower-retrieval").smoke_config,
                           user_vocab=4096, item_vocab=4096)
 params = RS.init_params(jax.random.PRNGKey(0), cfg)
