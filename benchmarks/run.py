@@ -31,6 +31,7 @@ from __future__ import annotations
 import argparse
 import sys
 import time
+from pathlib import Path
 
 
 def main() -> int:
@@ -41,7 +42,7 @@ def main() -> int:
     args = p.parse_args()
 
     from repro.runtime.compile_cache import enable_compile_cache
-    enable_compile_cache()
+    enable_compile_cache(Path(__file__).resolve().parents[1])
 
     from benchmarks import (fig4_edgecut, fig5_vs_offline, fig6_dynamics,
                             fig7_imbalance, fig8_npartitions, fig9_scaling,

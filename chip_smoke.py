@@ -14,7 +14,9 @@ the first events is checked against the pure-Python oracle
 ``engine="scan"`` session, a ``use_kernel=True`` session (the compiled
 Pallas kernels) and a ``PartitionService`` (``submit``, ``flush``,
 ``where_many``, ``route``). Each must end in the windowed session's state,
-bit for bit.
+bit for bit. Last, a ``Sweep`` of sdp, greedy and ldg lanes at n = 2**16
+runs through the compiled fused chooser (``.kernel()``) and must match
+the XLA window sweep lane for lane.
 
 Four chips (``--four-chips``; this phase alone). A ``sharded=True``
 session at n = 2**22 across the four chips must match a dense session on
@@ -38,7 +40,8 @@ from pathlib import Path
 
 import numpy as np
 
-sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
+REPO = Path(__file__).resolve().parent
+sys.path.insert(0, str(REPO / "src"))
 
 MAX_DEG = 192
 WINDOW = 256
@@ -48,6 +51,8 @@ EVENTS = 20_000
 ORACLE_EVENTS = 4_096
 CHUNKS = (1000, 333, 2500, 77, 1800, 5)   # uneven feed() sizes, cycled
 QUERIES = 300
+SWEEP_N = 1 << 16
+SWEEP_LANES = ("sdp", "greedy", "ldg")
 ROW_FIELDS = ("assignment", "present", "adj")
 
 
@@ -239,6 +244,25 @@ def one_chip(n: int = N_ONE_CHIP, events: int = EVENTS,
           f"{len(edges)} edges, cut={int(routed.cut.sum())}; "
           f"batches_dispatched={sm['batches_dispatched']}", flush=True)
 
+    sweep_kernel(seed)
+
+
+def sweep_kernel(seed: int) -> None:
+    """``Sweep(...).kernel()``: lanes of several policies through the
+    compiled fused chooser, lane for lane equal to the XLA window sweep."""
+    from repro.api import Sweep
+    from repro.graph.stream import powerlaw_churn
+
+    stream = powerlaw_churn(SWEEP_N, 8 * WINDOW, max_deg=MAX_DEG,
+                            seed=seed + 3)
+    runs = [(p, cfg(), seed) for p in SWEEP_LANES]
+    with Clock("sweep kernel"):
+        want = Sweep(stream).lanes(runs).windowed(WINDOW).run()
+        got = Sweep(stream).lanes(runs).windowed(WINDOW).kernel().run()
+    for w, g in zip(want, got):
+        check_same(f"sweep kernel lane {w.policy} vs XLA lane",
+                   host_state(g.state, SWEEP_N), host_state(w.state, SWEEP_N))
+
 
 def four_chips(n: int = N_ONE_CHIP, n_big: int = N_BEYOND_ONE_CHIP,
                events: int = 8_192, big_windows: int = 8,
@@ -310,7 +334,7 @@ def main() -> int:
     args = p.parse_args()
 
     from repro.runtime.compile_cache import enable_compile_cache
-    cache = enable_compile_cache()
+    cache = enable_compile_cache(REPO)
     import jax
 
     devices = jax.devices()

@@ -435,22 +435,24 @@ def policy_fns_at(balance_guard: str):
             _choose_greedy_at)
 
 
-def make_table_chooser(balance_guard: str, policy: str | None = None,
+def make_table_chooser(balance_guard: str, policy: str | tuple[str, ...],
                        policy_idx: jax.Array | None = None) -> Callable:
     """``choose(state, scores, deg, v, ridx, kn, n) -> p`` — the
     ``make_chooser`` contract with the PRNG key replaced by the precomputed
-    random index ``ridx`` (see ``rand_index_table``). Same static-string /
-    traced-index parameterization; the traced form is built *inside* the
-    fused kernel body so the lax.switch runs on the kernel's scalars."""
+    random index ``ridx`` (see ``rand_index_table``). A string ``policy``
+    is a trace-time table pick; a tuple is the traced knob, a lax.switch
+    over just those policies with ``policy_idx`` indexing the tuple (the
+    sweep passes its lanes' policies, so a branch no lane takes is never
+    compiled). The traced form is built *inside* the fused kernel body so
+    the switch runs on the kernel's scalars."""
     table = policy_fns_at(balance_guard)
-    if (policy is None) == (policy_idx is None):
-        raise ValueError("pass exactly one of policy / policy_idx")
-    if policy is not None:
+    if isinstance(policy, str):
         return table[POLICY_INDEX[policy]]
+    branches = [table[POLICY_INDEX[p]] for p in policy]
 
     def choose(state, scores, deg, v, ridx, kn, n):
         return jax.lax.switch(
-            policy_idx, list(table), state, scores, deg, v, ridx, kn, n)
+            policy_idx, branches, state, scores, deg, v, ridx, kn, n)
     return choose
 
 

@@ -386,45 +386,41 @@ def poisson_arrivals(
     return bounds, np.cumsum(gaps)
 
 
-def powerlaw_churn(
-    n: int,
-    num_events: int,
-    *,
-    max_deg: int = 192,
-    exponent: float = 2.3,
-    min_deg: int = 2,
-    vertex_del_frac: float = 0.15,
-    edge_del_frac: float = 0.15,
-    warmup: int = 512,
-    seed: int = 0,
-) -> VertexStream:
+# powerlaw_churn's fixed shape: the degree law and the churn mix. A
+# benchmark that runs the stream lists these under ``assumed``.
+CHURN_DEG_EXPONENT = 2.3     # Pareto tail of the links per arrival
+CHURN_MIN_DEG = 2            # links an arrival asks for, at least
+CHURN_VERTEX_DEL = 0.15      # share of events that delete a vertex
+CHURN_EDGE_DEL = 0.15        # share of events that delete an edge
+CHURN_WARMUP = 512           # leading events that are all adds
+
+
+def powerlaw_churn(n: int, num_events: int, *, max_deg: int = 192,
+                   seed: int = 0) -> VertexStream:
     """Seeded power-law churn stream over a large vertex universe, built
     in bulk (no per-vertex Python graph construction, so ``n`` in the
     millions costs nothing beyond the ids the stream touches).
 
-    The first ``warmup`` events are adds; after that each event is an
-    add, a vertex delete or an edge delete with the given shares. Added
-    vertices take distinct ids drawn uniformly from ``[0, n)``. Arrival
-    ``j`` links to ``d_j`` earlier arrivals, ``d_j`` from a Pareto tail of
-    the given ``exponent`` starting at ``min_deg``; the partner index is
-    ``floor(j * u**2)`` for uniform ``u``, so early arrivals collect
-    links and become hubs. The undirected graph is fixed up front and
-    both endpoints list every edge (the generator discipline of the
-    adversarial streams below); an edge that would overflow ``max_deg``
-    at either endpoint is dropped from both, counted in
-    ``truncated_nbrs``. Deletes name only present vertices and live
-    edges between present vertices; nothing is re-added.
+    The first ``CHURN_WARMUP`` events are adds; after that each event is
+    an add, a vertex delete or an edge delete, with the ``CHURN_*_DEL``
+    shares. Added vertices take distinct ids drawn uniformly from
+    ``[0, n)``. Arrival ``j`` links to ``d_j`` earlier arrivals, ``d_j``
+    from a Pareto tail of exponent ``CHURN_DEG_EXPONENT`` starting at
+    ``CHURN_MIN_DEG``; the partner index is ``floor(j * u**2)`` for
+    uniform ``u``, so early arrivals collect links and become hubs. The
+    undirected graph is fixed up front and both endpoints list every edge
+    (the generator discipline of the adversarial streams below); an edge
+    that would overflow ``max_deg`` at either endpoint is dropped from
+    both, counted in ``truncated_nbrs``. Deletes name only present
+    vertices and live edges between present vertices; nothing is
+    re-added.
     """
-    add_frac = 1.0 - vertex_del_frac - edge_del_frac
-    if add_frac <= 0 or min(vertex_del_frac, edge_del_frac) < 0:
-        raise ValueError(
-            f"vertex_del_frac={vertex_del_frac} and edge_del_frac="
-            f"{edge_del_frac} must be >= 0 and leave a positive add share")
+    add_frac = 1.0 - CHURN_VERTEX_DEL - CHURN_EDGE_DEL
     rng = np.random.default_rng(seed)
     kinds = rng.choice(
         np.asarray([EVENT_ADD, EVENT_DEL_VERTEX, EVENT_DEL_EDGE], np.int32),
-        size=num_events, p=[add_frac, vertex_del_frac, edge_del_frac])
-    kinds[:warmup] = EVENT_ADD
+        size=num_events, p=[add_frac, CHURN_VERTEX_DEL, CHURN_EDGE_DEL])
+    kinds[:CHURN_WARMUP] = EVENT_ADD
     n_add = int(np.sum(kinds == EVENT_ADD))
     if n_add > n:
         raise ValueError(f"{n_add} adds need n >= {n_add} distinct ids, "
@@ -432,8 +428,9 @@ def powerlaw_churn(
     ids = rng.choice(n, size=n_add, replace=False).astype(np.int32)
 
     # --- the static graph, in arrival-index space -----------------------
-    deg = np.minimum(min_deg * (1.0 - rng.random(n_add))
-                     ** (-1.0 / (exponent - 1.0)), max_deg).astype(np.int64)
+    deg = np.minimum(CHURN_MIN_DEG * (1.0 - rng.random(n_add))
+                     ** (-1.0 / (CHURN_DEG_EXPONENT - 1.0)),
+                     max_deg).astype(np.int64)
     j = np.arange(n_add, dtype=np.int64)[:, None]
     pick = (j * rng.random((n_add, max_deg)) ** 2).astype(np.int64)
     links = (np.arange(max_deg)[None, :] < deg[:, None]) & (j > 0)

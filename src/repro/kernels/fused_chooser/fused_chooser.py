@@ -29,12 +29,12 @@ scale-in merges over committed labels — the score tile never leaves VMEM,
 and the policy chooser is the *same table* as the engines
 (``transition.make_table_chooser``: the ``make_chooser`` bodies with the
 single random draw precomputed by ``transition.rand_index_table``). Both
-knob bindings exist: static policy string (single runs) and traced
-policy_idx via lax.switch on a kernel scalar (sweep lanes, vmapped over
-the pallas_call). Every index is in the mask form Mosaic lowers (see
-``make_slot_step``); the kernel compiles for a TPU for every policy but
-fennel, whose float power Mosaic cannot lower
-(tests/test_v5e_compile.py).
+knob bindings exist: static policy string (single runs) and a traced
+index via lax.switch on a kernel scalar over the lanes' policies (sweep
+lanes, vmapped over the pallas_call). Every index is in the mask form
+Mosaic lowers (see ``make_slot_step``); the kernel compiles for a TPU for
+every policy but fennel, whose float power Mosaic cannot lower, and for
+a switch over any set of them without fennel (tests/test_v5e_compile.py).
 
 Bit-identity with `run_stream` (all policies, autoscale on, interleaved
 churn) is the contract — tests/test_fused_chooser.py; `ref.py` is the
@@ -123,12 +123,19 @@ def _cond(pred, f, operand):
     return from_i32(out)
 
 
-def _gather_col(col, idx):
+def gather_col_one_hot(col, idx):
     """``col[idx, 0]`` for a (M, 1) column and a (1, D) row of in-range
-    indices, as a (1, D) row: a one-hot (M, D) compare and a sum over M."""
+    indices, as a (1, D) row: a one-hot (M, D) compare and a sum over M —
+    the form Mosaic lowers, O(M·D) per call."""
     hit = jax.lax.broadcasted_iota(jnp.int32, (col.shape[0],) + idx.shape[1:],
                                    0) == idx
     return jnp.sum(jnp.where(hit, col, 0), axis=0, keepdims=True)
+
+
+def gather_col_take(col, idx):
+    """``gather_col_one_hot`` as a plain O(D) XLA gather; equal to it on
+    in-range indices, which are the only ones the slot step passes."""
+    return jnp.take(col[:, 0], idx)
 
 
 def _col_at(col, i):
@@ -138,7 +145,7 @@ def _col_at(col, i):
 
 
 def make_slot_step(*, k_max: int, n: int, choose, autoscaling: bool,
-                   dynamic: bool):
+                   dynamic: bool, gather_col):
     """One window slot on the touch-table representation.
 
     ``choose`` is a ``transition.make_table_chooser`` chooser. The body
@@ -150,7 +157,10 @@ def make_slot_step(*, k_max: int, n: int, choose, autoscaling: bool,
     Shapes are the ones Mosaic lowers: K-vectors of ``small`` are (1, K)
     rows, ``w_label`` is a (W, 1) column and ``remap`` a (K, 1) column;
     ``ev`` is the slot's EV_COLS scalars, ``src_lbl``/``touch`` are (1, D)
-    rows and ``rand_row`` a (1, K) row. Every index is a mask compare.
+    rows and ``rand_row`` a (1, K) row. Every index is a mask compare but
+    the (1, D) neighbour-label lookups, which go through ``gather_col``:
+    the kernel passes ``gather_col_one_hot``, the XLA oracle (which the
+    sharded session runs every window) ``gather_col_take``.
     """
 
     def slot_step(small: SmallState, w_label, remap, kn, do_scale, i,
@@ -173,9 +183,9 @@ def make_slot_step(*, k_max: int, n: int, choose, autoscaling: bool,
         # --- effective neighbour labels + affinity (paper Eq. 1): the
         # last in-window decision if touched, else the committed label
         # pushed through the scale-in remap ---
-        in_win = _gather_col(w_label, jnp.maximum(touch, 0))
+        in_win = gather_col(w_label, jnp.maximum(touch, 0))
         committed = jnp.where(src_lbl >= 0,
-                              _gather_col(remap, jnp.maximum(src_lbl, 0)), -1)
+                              gather_col(remap, jnp.maximum(src_lbl, 0)), -1)
         eff = jnp.where(touch >= 0, in_win, committed)        # (1, D)
         sc_eff, deg_eff = row_histogram(eff, k_max)
         ridx = tx.take_at(rand_row, jnp.maximum(small.num_partitions, 1) - 1)
@@ -242,11 +252,11 @@ def _read_small(active_ref, loads_ref, cutmat_ref, scal_ref) -> SmallState:
         active=active_ref[...] != 0,
         edge_load=loads_ref[0:1, :],
         vertex_count=loads_ref[1:2, :],
-        num_partitions=scal_ref[SCAL_NP],
-        total_edges=scal_ref[SCAL_TOTAL],
-        cut_edges=scal_ref[SCAL_CUT],
-        denied_scaleout=scal_ref[SCAL_DENIED],
-        scale_events=scal_ref[SCAL_SCALE],
+        num_partitions=scal_ref[0, SCAL_NP],
+        total_edges=scal_ref[0, SCAL_TOTAL],
+        cut_edges=scal_ref[0, SCAL_CUT],
+        denied_scaleout=scal_ref[0, SCAL_DENIED],
+        scale_events=scal_ref[0, SCAL_SCALE],
         cut_matrix=cutmat_ref[...],
     )
 
@@ -255,22 +265,20 @@ def _fused_kernel(ev_ref, scal_ref, knobs_ref, flags_ref, srclbl_ref,
                   touch_ref, rand_ref, active_ref, loads_ref, cutmat_ref,
                   wlabel_ref, psel_ref, remap_ref, active_o_ref, loads_o_ref,
                   cutmat_o_ref, scal_o_ref, *, w: int, k_max: int, n: int,
-                  policy: str | None, balance_guard: str, autoscaling: bool,
-                  dynamic: bool):
+                  policy: str | tuple[str, ...], balance_guard: str,
+                  autoscaling: bool, dynamic: bool):
     """Single-program kernel: the per-slot scalars, counters, knobs and
-    flags live in SMEM, the (W, D) tables and O(K²) counters in VMEM; a
-    fori_loop walks the W slots carrying only O(K)+O(W) values. Policy
-    dispatch is static (trace-time table pick) when ``policy`` is a
-    string, else a lax.switch over the table on the ``flags`` scalar."""
-    kn = tx.Knobs(*(knobs_ref[j] for j in range(7)))
-    if policy is not None:
-        choose = tx.make_table_chooser(balance_guard, policy=policy)
-    else:
-        choose = tx.make_table_chooser(balance_guard,
-                                       policy_idx=flags_ref[0])
-    do_scale = flags_ref[1] != 0
+    flags live in SMEM as (1, X) rows, the (W, D) tables and O(K²)
+    counters in VMEM; a fori_loop walks the W slots carrying only
+    O(K)+O(W) values. Policy dispatch is static (trace-time table pick)
+    when ``policy`` is a string, else a lax.switch over those policies on
+    the ``flags`` scalar."""
+    kn = tx.Knobs(*(knobs_ref[0, j] for j in range(7)))
+    choose = tx.make_table_chooser(balance_guard, policy, flags_ref[0, 0])
+    do_scale = flags_ref[0, 1] != 0
     slot_step = make_slot_step(k_max=k_max, n=n, choose=choose,
-                               autoscaling=autoscaling, dynamic=dynamic)
+                               autoscaling=autoscaling, dynamic=dynamic,
+                               gather_col=gather_col_one_hot)
 
     # the loop carries ``active`` as int32: Mosaic carries no i1 vector
     small0 = _read_small(active_ref, loads_ref, cutmat_ref, scal_ref)
@@ -280,13 +288,13 @@ def _fused_kernel(ev_ref, scal_ref, knobs_ref, flags_ref, srclbl_ref,
 
     def body(i, carry):
         small, w_label, remap = carry
-        ev = [ev_ref[i * EV_COLS + c] for c in range(EV_COLS)]
+        ev = [ev_ref[0, i * EV_COLS + c] for c in range(EV_COLS)]
         row = pl.ds(i, 1)
         small, w_label, remap, p = slot_step(
             small._replace(active=small.active != 0), w_label, remap, kn,
             do_scale, i, ev,
             srclbl_ref[row, :], touch_ref[row, :], rand_ref[row, :])
-        psel_ref[i] = p
+        psel_ref[0, i] = p
         return small._replace(active=small.active.astype(jnp.int32)), \
             w_label, remap
 
@@ -302,30 +310,48 @@ def _fused_kernel(ev_ref, scal_ref, knobs_ref, flags_ref, srclbl_ref,
     for c, x in enumerate((small.num_partitions, small.total_edges,
                            small.cut_edges, small.denied_scaleout,
                            small.scale_events)):
-        scal_o_ref[c] = x
+        scal_o_ref[0, c] = x
+
+
+def _batch_axis_first(call):
+    """``call`` with a vmap rule that hands it its batched operands with
+    the batch axis first. A batched Mosaic block must keep the array's
+    last two dims, and plain vmap leaves the batch axis where the producer
+    put it: a lax.scan's stacked output carries it second."""
+    f = jax.custom_batching.custom_vmap(call)
+
+    @f.def_vmap
+    def rule(axis_size, in_batched, *args):
+        out = jax.vmap(call, in_axes=[0 if b else None for b in in_batched])(
+            *args)
+        return out, jax.tree_util.tree_map(lambda _: True, out)
+    return f
 
 
 def fused_window_choose(ev, src_lbl, touch, rand_tab, active, edge_load,
                         vertex_count, cut_matrix, scalars, knobs, flags, *,
-                        n: int, policy: str | None, balance_guard: str,
-                        autoscaling: bool, dynamic: bool,
+                        n: int, policy: str | tuple[str, ...],
+                        balance_guard: str, autoscaling: bool, dynamic: bool,
                         interpret: bool | None = None):
     """One pallas_call for one whole window.
 
     Inputs are the prep tables (`ops._prepare_window`), the per-slot random
     table (`transition.rand_index_table`), and the O(K) counter slice;
     outputs are (w_label, p_sel, remap, active, loads, cut_matrix,
-    scalars), 1-D where the slice is. ``interpret=None`` defers to
-    ``repro.kernels.common.default_interpret``. vmap over this call is the
-    sweep's lane batching (pallas_call lifts the batch to a grid axis).
+    scalars), 1-D where the slice is. ``policy`` is one policy, or a tuple
+    of them that ``flags[0]`` indexes (`transition.make_table_chooser`).
+    ``interpret=None`` defers to ``repro.kernels.common.default_interpret``.
+    vmap over this call is the sweep's lane batching (pallas_call lifts the
+    batch to a grid axis). Every operand is at least 2-D, so a lane's
+    block keeps the array's last two dims, as Mosaic requires of a
+    batched block.
     """
     interpret = resolve_interpret(interpret)
-    if not interpret and policy in (None, "fennel"):
+    policies = (policy,) if isinstance(policy, str) else policy
+    if not interpret and "fennel" in policies:
         raise NotImplementedError(
             "the fused chooser cannot compile fennel for a TPU (Mosaic "
-            "lowers no float power), nor a traced policy index, whose "
-            "switch holds fennel's branch; use a static policy other than "
-            "fennel, or run without the kernel")
+            "lowers no float power); run fennel without the kernel")
     w = ev.shape[0]
     k_max = int(rand_tab.shape[-1])
     kernel = functools.partial(
@@ -334,22 +360,25 @@ def fused_window_choose(ev, src_lbl, touch, rand_tab, active, edge_load,
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     i32 = jnp.int32
-    w_label, psel, remap, active, loads, cut_matrix, scal = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         in_specs=[smem] * 4 + [vmem] * 6,
         out_specs=[vmem, smem] + [vmem] * 4 + [smem],
         out_shape=[
             jax.ShapeDtypeStruct((w, 1), i32),              # w_label
-            jax.ShapeDtypeStruct((w,), i32),                # p_sel
+            jax.ShapeDtypeStruct((1, w), i32),              # p_sel
             jax.ShapeDtypeStruct((k_max, 1), i32),          # remap
             jax.ShapeDtypeStruct((1, k_max), i32),          # active
             jax.ShapeDtypeStruct((2, k_max), i32),          # loads
             jax.ShapeDtypeStruct((k_max, k_max), i32),      # cut_matrix
-            jax.ShapeDtypeStruct((SCAL_N,), i32),           # scalars
+            jax.ShapeDtypeStruct((1, SCAL_N), i32),         # scalars
         ],
         interpret=interpret,
-    )(ev.reshape(-1), scalars, knobs, flags, src_lbl, touch, rand_tab,
-      active.astype(i32).reshape(1, k_max),
-      jnp.stack([edge_load, vertex_count]), cut_matrix)
-    return (w_label[:, 0], psel, remap[:, 0], active[0], loads,
-            cut_matrix, scal)
+    )
+    w_label, psel, remap, active, loads, cut_matrix, scal = \
+        _batch_axis_first(call)(
+            ev.reshape(1, -1), scalars[None], knobs[None], flags[None],
+            src_lbl, touch, rand_tab, active.astype(i32).reshape(1, k_max),
+            jnp.stack([edge_load, vertex_count]), cut_matrix)
+    return (w_label[:, 0], psel[0], remap[:, 0], active[0], loads,
+            cut_matrix, scal[0])

@@ -120,9 +120,9 @@ def _fused_lane(
     state: PartitionState,
     ets, vs, rows, t0,
     knobs,               # (7,) f32 (transition.Knobs field order)
-    flags,               # (2,) int32 [policy_idx, do_scale]
+    flags,               # (2,) int32 [index into a policy tuple, do_scale]
     *,
-    policy: str | None,
+    policy: str | tuple[str, ...],
     balance_guard: str,
     autoscaling: bool,
     dynamic: bool,
@@ -203,6 +203,7 @@ def sweep_window_mixed_fused(
     *,
     balance_guard: str,
     autoscale_mode: str,      # "off" | "dynamic"
+    policies: tuple[str, ...],  # every lane's policy is one of these
     window: int = 256,
     shared_stream: bool = False,
     interpret: bool | None = None,
@@ -211,12 +212,12 @@ def sweep_window_mixed_fused(
     """Drop-in for `windowed.sweep_window_mixed` with the slot loop fused
     into the Pallas chooser: per lane, lax.scan over windows whose body
     dynamic-slices the next window and runs `_fused_lane` under the traced
-    knob (policy via lax.switch on a kernel scalar, autoscale via the
-    per-lane runtime gate). The vmap over lanes lifts the pallas_call's
-    batch to a grid axis — one kernel launch per window step covering all
-    lanes. Same contract as the XLA version: T a multiple of ``window``,
-    ``shared_stream`` broadcast semantics, not jitted here (the sweep
-    runtime wraps it)."""
+    knob (policy via lax.switch over ``policies`` on a kernel scalar,
+    autoscale via the per-lane runtime gate). The vmap over lanes lifts
+    the pallas_call's batch to a grid axis — one kernel launch per window
+    step covering all lanes. Same contract as the XLA version: T a
+    multiple of ``window``, ``shared_stream`` broadcast semantics, not
+    jitted here (the sweep runtime wraps it)."""
     check_row_width(states, rows)
     dynamic = autoscale_mode == "dynamic"
     sdp_idx = tx.POLICY_INDEX["sdp"]
@@ -225,7 +226,9 @@ def sweep_window_mixed_fused(
         do = auto & (pidx == sdp_idx)
         knobs = jnp.stack([jnp.float32(x) for x in kn])
         gate = do if dynamic else jnp.bool_(True)
-        flags = jnp.stack([pidx, gate.astype(jnp.int32)])
+        branch = sum(jnp.where(pidx == tx.POLICY_INDEX[p], j, 0)
+                     for j, p in enumerate(policies))
+        flags = jnp.stack([branch, gate.astype(jnp.int32)])
         n_windows = ets_l.shape[0] // window
 
         def body(s, wdx):
@@ -236,7 +239,7 @@ def sweep_window_mixed_fused(
                 jax.lax.dynamic_slice_in_dim(vs_l, i0, window),
                 jax.lax.dynamic_slice_in_dim(rows_l, i0, window),
                 t0 + i0, knobs, flags,
-                policy=None, balance_guard=balance_guard,
+                policy=policies, balance_guard=balance_guard,
                 autoscaling=dynamic, dynamic=dynamic,
                 interpret=interpret, variant=variant)
             return s, None

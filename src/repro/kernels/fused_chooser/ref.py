@@ -11,26 +11,27 @@ import jax.numpy as jnp
 from repro.core import transition as tx
 from repro.core.windowed import SmallState
 from repro.kernels.fused_chooser.fused_chooser import (
-    SCAL_CUT, SCAL_DENIED, SCAL_NP, SCAL_SCALE, SCAL_TOTAL, make_slot_step,
+    SCAL_CUT, SCAL_DENIED, SCAL_NP, SCAL_SCALE, SCAL_TOTAL, gather_col_take,
+    make_slot_step,
 )
 
 
 def fused_window_choose_ref(ev, src_lbl, touch, rand_tab, active, edge_load,
                             vertex_count, cut_matrix, scalars, knobs, flags,
-                            *, n: int, policy: str | None, balance_guard: str,
-                            autoscaling: bool, dynamic: bool):
+                            *, n: int, policy: str | tuple[str, ...],
+                            balance_guard: str, autoscaling: bool,
+                            dynamic: bool):
     """Same signature and outputs as `fused_chooser.fused_window_choose`
-    (minus ``interpret``), pure XLA."""
+    (minus ``interpret``), pure XLA: neighbour labels are gathered with
+    a plain O(D) take where the kernel needs an O(W·D) one-hot."""
     w = ev.shape[0]
     k_max = int(rand_tab.shape[-1])
     kn = tx.Knobs(*(knobs[j] for j in range(7)))
-    if policy is not None:
-        choose = tx.make_table_chooser(balance_guard, policy=policy)
-    else:
-        choose = tx.make_table_chooser(balance_guard, policy_idx=flags[0])
+    choose = tx.make_table_chooser(balance_guard, policy, flags[0])
     do_scale = flags[1] != 0
     slot_step = make_slot_step(k_max=k_max, n=n, choose=choose,
-                               autoscaling=autoscaling, dynamic=dynamic)
+                               autoscaling=autoscaling, dynamic=dynamic,
+                               gather_col=gather_col_take)
 
     def row(x):
         return x.reshape(1, k_max)

@@ -6,8 +6,9 @@ turns the persistent cache on: in ``JAX_COMPILATION_CACHE_DIR`` when that
 is set (JAX reads the variable itself, and nothing here overrides it),
 otherwise in ``<repo>/.jax_cache``. The path is fixed because it is part
 of the cache key: a directory that moves never hits. Only entry points
-(``chip_smoke.py``, ``benchmarks/run.py``) call this; importing ``repro``
-leaves the cache off.
+(``chip_smoke.py``, ``benchmarks/run.py``) call this, each naming the
+checkout it sits in, so an installed package never picks a directory of
+its own; importing ``repro`` leaves the cache off.
 """
 from __future__ import annotations
 
@@ -17,15 +18,17 @@ from pathlib import Path
 import jax
 
 ENV = "JAX_COMPILATION_CACHE_DIR"
-DEFAULT_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+DIRNAME = ".jax_cache"
 
 
-def enable_compile_cache() -> str:
-    """Turn the persistent cache on and return its directory. Caches
+def enable_compile_cache(repo_root: Path) -> str:
+    """Turn the persistent cache on and return its directory:
+    ``JAX_COMPILATION_CACHE_DIR`` when set, else ``repo_root/.jax_cache``
+    (``repo_root`` is the checkout of the calling entry point). Caches
     every program, however quick its compile."""
     path = os.environ.get(ENV)
     if not path:
-        path = str(DEFAULT_DIR)
+        path = str(Path(repo_root).resolve() / DIRNAME)
         jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     return path

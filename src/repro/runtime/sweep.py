@@ -148,7 +148,8 @@ _JITTED = {
     # pallas_call); bit-identical to "windowed", selected by use_kernel
     "windowed_fused": jax.jit(
         sweep_window_mixed_fused,
-        static_argnames=_STATICS + ("window", "interpret", "variant"),
+        static_argnames=_STATICS + ("policies", "window", "interpret",
+                                    "variant"),
         donate_argnums=(0,)),
 }
 _KERNELS = {
@@ -160,7 +161,8 @@ _KERNELS = {
 
 @functools.lru_cache(maxsize=None)
 def _sharded_kernel(kind: str, n_devices: int, balance_guard: str,
-                    autoscale_mode: str, shared_stream: bool, window: int):
+                    autoscale_mode: str, shared_stream: bool, window: int,
+                    policies: tuple[str, ...]):
     """jit(shard_map(vmapped kernel)) over the "lanes" mesh. Lanes are
     embarrassingly parallel: every lane-stacked operand shards on axis 0,
     the scalar t0 (and the stream, when shared) is replicated, and no
@@ -172,6 +174,8 @@ def _sharded_kernel(kind: str, n_devices: int, balance_guard: str,
           "shared_stream": shared_stream}
     if kind in ("windowed", "windowed_fused"):
         kw["window"] = window
+    if kind == "windowed_fused":
+        kw["policies"] = policies
     base = functools.partial(_KERNELS[kind], **kw)
     return jax.jit(jax.shard_map(
         base, mesh=mesh,
@@ -312,6 +316,11 @@ def _execute_sweep(
 
     kind = ("windowed_fused" if engine == "windowed" and use_kernel
             else engine)
+    # the fused kernel switches over these alone (POLICIES order), so a
+    # policy no lane runs is never compiled — on a TPU, fennel only
+    # refuses when a lane runs it
+    policies = tuple(p for p in tx.POLICIES if any(r.policy == p
+                                                   for r in runs))
 
     L = len(runs)
     lens = [s.num_events for s in streams]
@@ -340,12 +349,14 @@ def _execute_sweep(
         if not shared:
             et, vx, nb = (_pad_lanes(x, lane_pad) for x in (et, vx, nb))
         call = _sharded_kernel(kind, ndev, cfg0.balance_guard,
-                               autoscale_mode, shared, window)
+                               autoscale_mode, shared, window, policies)
     else:
         kw = {"balance_guard": cfg0.balance_guard,
               "autoscale_mode": autoscale_mode, "shared_stream": shared}
         if engine == "windowed":
             kw["window"] = window
+        if kind == "windowed_fused":
+            kw["policies"] = policies
         call = functools.partial(_JITTED[kind], **kw)
 
     def ev_slice(a, sl):

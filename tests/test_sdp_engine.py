@@ -241,8 +241,8 @@ def test_powerlaw_churn_discipline():
     """Seeded and reproducible; ids spread over the universe; rows list
     every edge at both endpoints; deletes name present vertices and live
     edges between present vertices."""
-    s = gstream.powerlaw_churn(1 << 20, 3000, max_deg=48, warmup=200, seed=4)
-    t = gstream.powerlaw_churn(1 << 20, 3000, max_deg=48, warmup=200, seed=4)
+    s = gstream.powerlaw_churn(1 << 20, 3000, max_deg=48, seed=4)
+    t = gstream.powerlaw_churn(1 << 20, 3000, max_deg=48, seed=4)
     np.testing.assert_array_equal(s.nbrs, t.nbrs)
     assert set(np.unique(s.etype)) == {gstream.EVENT_ADD,
                                        gstream.EVENT_DEL_VERTEX,
@@ -275,7 +275,7 @@ def test_engine_matches_oracle_powerlaw_churn(use_kernel):
     up and down: the windowed engine (XLA or the fused kernel) and the
     scan agree with the oracle."""
     from repro.core import run_stream_windowed
-    s = gstream.powerlaw_churn(1 << 12, 1500, max_deg=32, warmup=200, seed=6)
+    s = gstream.powerlaw_churn(1 << 12, 1500, max_deg=32, seed=6)
     cfg = EngineConfig(k_max=8, k_init=1, max_cap=300)
     ref = run_reference(s, policy="sdp", cfg=cfg, seed=2)
     assert ref.scale_events > 2

@@ -18,13 +18,16 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 
 from repro.api import partitioner as part_api
+from repro.core import transition as tx
 from repro.core.config import EngineConfig
 from repro.core.sharded_state import state_specs
 from repro.core.state import init_state
 from repro.kernels.fused_chooser.fused_chooser import (
     EV_COLS, SCAL_N, fused_window_choose,
 )
-from repro.kernels.fused_chooser.ops import _run_window_mixed_fused
+from repro.kernels.fused_chooser.ops import (
+    _run_window_mixed_fused, sweep_window_mixed_fused,
+)
 from repro.kernels.partition_affinity.partition_affinity import (
     partition_affinity,
 )
@@ -80,26 +83,59 @@ def _chooser_args(sharding):
 
 
 def _chooser(policy):
+    """A string is one session's policy; a tuple is a sweep's lanes,
+    switched on a traced index with the per-lane autoscale gate."""
+    lanes = isinstance(policy, tuple)
     return functools.partial(
         fused_window_choose, n=1 << 22, policy=policy, balance_guard="text",
-        autoscaling=policy == "sdp", dynamic=False, interpret=False)
+        autoscaling=lanes or policy == "sdp", dynamic=lanes, interpret=False)
 
 
-@pytest.mark.parametrize("policy", ["sdp", "greedy", "ldg", "hash", "random"])
+@pytest.mark.parametrize("policy", [
+    "sdp", "greedy", "ldg", "hash", "random",
+    ("sdp", "ldg", "hash", "random", "greedy")])
 def test_fused_chooser_compiles(one_chip, policy):
     """The fused window chooser lowers through Mosaic for every policy
-    whose body it can express."""
+    whose body it can express, and for a sweep's switch over them."""
     c = jax.jit(_chooser(policy)).lower(*_chooser_args(one_chip)).compile()
     assert "tpu_custom_call" in c.as_text()
 
 
-@pytest.mark.parametrize("policy", ["fennel", None])
+@pytest.mark.parametrize("policy", ["fennel", ("sdp", "fennel")])
 def test_fused_chooser_refuses_float_power(one_chip, policy):
-    """Fennel's float power has no Mosaic lowering, and a traced policy
-    index switches over fennel's branch too: the compiled kernel refuses
-    both by name instead of failing inside Mosaic."""
+    """Fennel's float power has no Mosaic lowering: the compiled kernel
+    refuses it by name, alone or as one of a sweep's lanes, instead of
+    failing inside Mosaic."""
     with pytest.raises(NotImplementedError, match="fennel"):
         jax.jit(_chooser(policy)).lower(*_chooser_args(one_chip))
+
+
+def test_sweep_kernel_lanes_compile(one_chip):
+    """``Sweep(...).kernel()``'s lane-batched program (vmap over the
+    pallas_call) compiles when no lane runs fennel: the kernel's switch
+    holds only the lanes' policies."""
+    n, lanes = 1 << 12, ("sdp", "greedy")
+
+    def inputs():
+        states = jax.tree_util.tree_map(
+            lambda *xs: jnp.stack(xs),
+            *[init_state(n, D, K, 1, i) for i in range(len(lanes))])
+        kns = jax.tree_util.tree_map(
+            lambda *xs: jnp.stack(xs),
+            *[tx.knobs_arrays(CFG, n) for _ in lanes])
+        pidx = jnp.asarray([tx.POLICY_INDEX[p] for p in lanes], jnp.int32)
+        return states, kns, pidx, jnp.ones(len(lanes), bool)
+
+    args = jax.tree_util.tree_map(
+        lambda a: _shape(a.shape, a.dtype, one_chip), jax.eval_shape(inputs))
+    ev = [_shape((W,), jnp.int32, one_chip), _shape((W,), jnp.int32, one_chip),
+          _shape((W, D), jnp.int32, one_chip), _shape((), jnp.int32, one_chip)]
+    fn = jax.jit(functools.partial(
+        sweep_window_mixed_fused, balance_guard="text",
+        autoscale_mode="dynamic", policies=lanes, window=W,
+        shared_stream=True, interpret=False))
+    c = fn.lower(*args, *ev).compile()
+    assert "tpu_custom_call" in c.as_text()
 
 
 @pytest.mark.parametrize("use_kernel", [False, True])
