@@ -157,7 +157,6 @@ def run(quick: bool = True) -> list:
         dict(base, variant="service", seconds=dur_svc, events_per_s=eps_svc,
              speedup_vs_sync=eps_svc / max(eps_sync, 1e-9),
              batches_dispatched=svc_m["batches_dispatched"],
-             device_busy_fraction=svc_m["device_busy_fraction"],
              coercion_s=svc_m["coercion_s"],
              device_wait_s=svc_m["device_wait_s"],
              submit_blocked_s=svc_m["submit_blocked_s"],
@@ -177,6 +176,5 @@ def summarize(rows) -> list[str]:
         f"events_per_s={svc['events_per_s']:.0f}"
         f";speedup_vs_sync={svc['speedup_vs_sync']:.2f}x"
         f";p99_ms={svc['p99_ms']:.1f}(sync={sync['p99_ms']:.1f})"
-        f";busy={svc['device_busy_fraction']:.2f}"
         f";states_match={svc['states_match_reference']}"
     ]

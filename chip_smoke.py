@@ -209,7 +209,8 @@ def one_chip(n: int = N_ONE_CHIP, events: int = EVENTS,
     with Clock("kernel"):
         part = feed_all(session(use_kernel=True), chunks)
         got = host_state(part.state, n)
-        kernel_windows = part.metrics()["kernel_windows"]
+        windows = part.metrics()["windows"]
+        kernel_windows = windows["mixed_kernel"] + windows["adds_kernel"]
     assert kernel_windows >= 64, f"only {kernel_windows} kernel windows"
     check_same("use_kernel vs windowed", got, want)
     del part

@@ -95,8 +95,13 @@ from repro.graph.stream import (
     EVENT_ADD, EVENT_PAD, VertexStream, normalize_rows, required_geometry_of,
 )
 from repro.rebalance import rebalance_jit
+from repro.runtime import telemetry
 
 _ENGINES = ("auto", "scan", "windowed")
+# the programs a feed dispatches, as ``metrics()["windows"]`` counts them:
+# the per-event scan, the dense ADD-only and mixed window programs (and
+# their Pallas forms under use_kernel), and the vertex-sharded window
+_PATHS = ("scan", "adds", "mixed", "adds_kernel", "mixed_kernel", "sharded")
 
 # Donated re-jits of the engine kernels: the session immediately rebinds
 # its carried state to each call's result, so donation lets XLA reuse the
@@ -184,9 +189,9 @@ class Partitioner:
         total: the per-event scan backend — ``engine="scan"``,
         ``collect_trace``, and ``engine="auto"``'s small tails — always
         runs pure XLA (it is the faithful reference the kernels are
-        verified against). ``metrics()`` reports the split as
-        ``kernel_windows`` vs ``fallback_windows`` so a session can tell
-        how much of its stream actually rode the kernels.
+        verified against). ``metrics()["windows"]`` counts the
+        dispatches of each path, so a session can tell how much of its
+        stream actually rode the kernels.
       auto_shrink: run the hysteretic ``maybe_shrink()`` check every
         ``shrink_every`` ingested events, so a long-lived session whose
         graph bulk-deleted drops back down the tiers without anyone
@@ -335,8 +340,8 @@ class Partitioner:
         self._rebalances = 0
         self._rebalance_moves = 0
         self._rebalance_events: list[dict] = []
-        self._kernel_windows = 0
-        self._fallback_windows = 0
+        self._windows = dict.fromkeys(_PATHS, 0)
+        self._pad_slots = 0
         self._sharded = bool(sharded)
         self._mesh = None
         if self._sharded:
@@ -726,7 +731,8 @@ class Partitioner:
         ``feed_prepared(prepare(events))``; dispatch is asynchronous
         (JAX async dispatch) — call ``sync()`` to block on completion.
         """
-        return self.feed_prepared(self.prepare(events))
+        with telemetry.span("session.feed"):
+            return self._feed_prepared(self.prepare(events))
 
     def prepare(self, events) -> PreparedChunk:
         """Host-only coercion: validate ``events`` (a
@@ -736,29 +742,31 @@ class Partitioner:
         work of a ``feed`` lives here, so a serving loop
         (repro.api.serve) can run it on chunk *t+1* while the device
         executes chunk *t*. Thread-safe with respect to the session."""
-        if isinstance(events, VertexStream):
-            et = np.asarray(events.etype, np.int32)
-            vx = np.asarray(events.vertex, np.int32)
-            nb = np.asarray(events.nbrs, np.int32)
-            required = events.required_geometry()
-        else:
-            try:
-                et, vx, nb = events
-            except (TypeError, ValueError):
-                raise TypeError(
-                    "feed() takes a VertexStream or an (etype, vertex, "
-                    f"nbrs) triple, got {type(events).__name__}") from None
-            et = np.atleast_1d(np.asarray(et, np.int32))
-            vx = np.atleast_1d(np.asarray(vx, np.int32))
-            nb = np.asarray(nb, np.int32)
-            if nb.ndim != 2 or et.shape != vx.shape \
-                    or nb.shape[0] != et.shape[0]:
-                raise ValueError(
-                    f"event triple shapes disagree: etype{et.shape}, "
-                    f"vertex{vx.shape}, nbrs{nb.shape} — want (T,), (T,), "
-                    "(T, max_deg)")
-            required = required_geometry_of(vx, nb)
-        return PreparedChunk(et, vx, nb, required)
+        with telemetry.span("session.prepare"):
+            if isinstance(events, VertexStream):
+                et = np.asarray(events.etype, np.int32)
+                vx = np.asarray(events.vertex, np.int32)
+                nb = np.asarray(events.nbrs, np.int32)
+                required = events.required_geometry()
+            else:
+                try:
+                    et, vx, nb = events
+                except (TypeError, ValueError):
+                    raise TypeError(
+                        "feed() takes a VertexStream or an (etype, vertex, "
+                        f"nbrs) triple, got {type(events).__name__}") \
+                        from None
+                et = np.atleast_1d(np.asarray(et, np.int32))
+                vx = np.atleast_1d(np.asarray(vx, np.int32))
+                nb = np.asarray(nb, np.int32)
+                if nb.ndim != 2 or et.shape != vx.shape \
+                        or nb.shape[0] != et.shape[0]:
+                    raise ValueError(
+                        f"event triple shapes disagree: etype{et.shape}, "
+                        f"vertex{vx.shape}, nbrs{nb.shape} — want (T,), "
+                        "(T,), (T, max_deg)")
+                required = required_geometry_of(vx, nb)
+            return PreparedChunk(et, vx, nb, required)
 
     def feed_prepared(self, chunk: PreparedChunk) -> "Partitioner":
         """Ingest a :class:`PreparedChunk` (see ``prepare``): grow the
@@ -767,15 +775,20 @@ class Partitioner:
         asynchronous — the call returns once the work is enqueued, and
         the carried state is a future until ``sync()`` (or any host
         read) blocks on it."""
-        # external ids → internal slots (identity until a relabeling
-        # compaction; allocates slots for first-seen ids)
-        chunk = self._translate(chunk)
-        # elastic: events beyond the current geometry grow the state
-        # (tier-doubled) instead of raising — the session's shapes are a
-        # starting point, not a contract
-        self._ensure_geometry(chunk.required)
-        et, vx = chunk.etype, chunk.vertex
-        nb = normalize_rows(chunk.nbrs, self.max_deg)
+        with telemetry.span("session.feed"):
+            return self._feed_prepared(chunk)
+
+    def _feed_prepared(self, chunk: PreparedChunk) -> "Partitioner":
+        with telemetry.span("session.ingest"):
+            # external ids → internal slots (identity until a relabeling
+            # compaction; allocates slots for first-seen ids)
+            chunk = self._translate(chunk)
+            # elastic: events beyond the current geometry grow the state
+            # (tier-doubled) instead of raising — the session's shapes
+            # are a starting point, not a contract
+            self._ensure_geometry(chunk.required)
+            et, vx = chunk.etype, chunk.vertex
+            nb = normalize_rows(chunk.nbrs, self.max_deg)
         T = chunk.num_events
         if T == 0:
             return self
@@ -816,13 +829,22 @@ class Partitioner:
             self.maybe_shrink()
         return self
 
+    def _dispatch(self, path: str, events: int, slots: int):
+        """Count one program call of ``path`` carrying ``events`` true
+        events in ``slots`` slots, and time its dispatch."""
+        self._windows[path] += 1
+        self._pad_slots += slots - events
+        return telemetry.span("session.dispatch", path=path, events=events,
+                              slots=slots)
+
     def _feed_scan(self, et, vx, nb):
         # the scan backend is outside the kernel surface (it is the
-        # faithful reference) — count it as fallback coverage
-        self._fallback_windows += 1
-        self._state, tr = _scan_donated(
-            self._state, jnp.asarray(et), jnp.asarray(vx), jnp.asarray(nb),
-            jnp.int32(self._cursor), policy=self.policy, cfg=self.cfg)
+        # faithful reference): its one program call carries no padding
+        with self._dispatch("scan", len(et), len(et)):
+            self._state, tr = _scan_donated(
+                self._state, jnp.asarray(et), jnp.asarray(vx),
+                jnp.asarray(nb), jnp.int32(self._cursor),
+                policy=self.policy, cfg=self.cfg)
         if self.collect_trace:
             self._traces.append(tr)
 
@@ -831,35 +853,35 @@ class Partitioner:
         Pad slots are no-ops that still occupy RNG indices past the true
         events — the cursor advances by the true count only, so the next
         call's fold_in indices line up with an unchopped run."""
+        w = self.window
         if self._sharded:
             from repro.runtime.shard_session import sharded_stream_fn
-            self._fallback_windows += 1
-            w = self.window
             fn = sharded_stream_fn(
                 self._mesh, n_sem=self._sem_geom.n, policy=self.policy,
                 cfg=self.cfg, window=w, n_events=w)
-            self._state = fn(
-                self._state, wnd._pad_to(jnp.asarray(et), w, EVENT_PAD),
-                wnd._pad_to(jnp.asarray(vx), w, -1),
-                wnd._pad_to(jnp.asarray(nb), w, -1),
-                jnp.int32(self._cursor))
+            with self._dispatch("sharded", len(et), w):
+                self._state = fn(
+                    self._state, wnd._pad_to(jnp.asarray(et), w, EVENT_PAD),
+                    wnd._pad_to(jnp.asarray(vx), w, -1),
+                    wnd._pad_to(jnp.asarray(nb), w, -1),
+                    jnp.int32(self._cursor))
             return
-        if self.use_kernel:
-            self._kernel_windows += 1
-        else:
-            self._fallback_windows += 1
-        w = self.window
-        vs_w = wnd._pad_to(vx, w, -1)
-        rows_w = wnd._pad_to(nb, w, -1)
-        t0 = jnp.int32(self._cursor)
-        if np.all(et == EVENT_ADD):
-            self._state = _adds_donated(
-                self._state, vs_w, rows_w, t0,
-                policy=self.policy, cfg=self.cfg, score_fn=self._score_fn)
-        else:
-            self._state = self._mixed_fn(
-                self._state, wnd._pad_to(et, w, EVENT_PAD),
-                vs_w, rows_w, t0, policy=self.policy, cfg=self.cfg)
+        adds = bool(np.all(et == EVENT_ADD))
+        path = ("adds" if adds else "mixed") \
+            + ("_kernel" if self.use_kernel else "")
+        with self._dispatch(path, len(et), w):
+            vs_w = wnd._pad_to(vx, w, -1)
+            rows_w = wnd._pad_to(nb, w, -1)
+            t0 = jnp.int32(self._cursor)
+            if adds:
+                self._state = _adds_donated(
+                    self._state, vs_w, rows_w, t0,
+                    policy=self.policy, cfg=self.cfg,
+                    score_fn=self._score_fn)
+            else:
+                self._state = self._mixed_fn(
+                    self._state, wnd._pad_to(et, w, EVENT_PAD),
+                    vs_w, rows_w, t0, policy=self.policy, cfg=self.cfg)
 
     def sync(self) -> "Partitioner":
         """Block until every dispatched feed has executed (feeds are
@@ -968,12 +990,10 @@ class Partitioner:
         m["shrinks"] = self._shrinks
         m["compactions"] = self._compactions
         m["state_bytes"] = state_bytes(self._state)
-        # kernel coverage: window dispatches that rode the Pallas kernels
-        # vs the XLA fallback (scan slices count as one fallback unit) —
-        # use_kernel=True with a large fallback share means the stream is
-        # mostly scan tails and the kernels barely engage
-        m["kernel_windows"] = self._kernel_windows
-        m["fallback_windows"] = self._fallback_windows
+        # program calls per path (a scan slice counts as one call) and
+        # the no-op slots that padded the windows among them
+        m["windows"] = dict(self._windows)
+        m["pad_slots"] = self._pad_slots
         m["rebalances"] = self._rebalances
         m["rebalance_moves"] = self._rebalance_moves
         m["rebalance_drift_fires"] = self._drift_fires

@@ -74,6 +74,7 @@ import numpy as np
 from repro.api.partitioner import Partitioner, PreparedChunk
 from repro.core.geometry import Geometry
 from repro.graph.stream import normalize_rows
+from repro.runtime import telemetry
 
 _POLICIES = ("block", "drop")
 _STOP = object()
@@ -202,7 +203,6 @@ class PartitionService:
         self._max_depth = 0
         self._coercion_s = 0.0
         self._device_wait_s = 0.0
-        self._device_busy_s = 0.0
         self._submit_blocked_s = 0.0
         self._latencies: list[float] = []
         self._t_start: float | None = None
@@ -215,8 +215,9 @@ class PartitionService:
         self._completer = threading.Thread(
             target=self._completion_loop, name="partition-complete",
             daemon=True)
-        # unbounded: holds (token, dispatch_time, [(arrival, n_events)])
-        # per in-flight batch for the completion thread
+        # unbounded: holds (token, batch id, dispatch time, [(chunk id,
+        # arrival, submitted, dequeued, n_events)]) per in-flight batch
+        # for the completion thread
         self._inflight: queue.Queue = queue.Queue()
         if autostart:
             self.start()
@@ -284,7 +285,8 @@ class PartitionService:
         if self._closed:
             raise RuntimeError("service is closed — no further submits")
         self._raise_pending()
-        item = (events, time.perf_counter() if arrival is None else arrival)
+        now = time.perf_counter()
+        item = (events, now if arrival is None else arrival, now)
         if self._t_start is None:
             self._t_start = item[1]
         if self.policy == "drop":
@@ -366,15 +368,24 @@ class PartitionService:
         with self._lock:
             return self._part.rebalance()
 
+    def _next_item(self):
+        """The next queued item; a wait for one is a ``serve.idle`` span.
+        Raises ``queue.Empty`` after ``_idle_s`` of silence (None: waits
+        for ever — the plain path)."""
+        try:
+            return self._queue.get_nowait()
+        except queue.Empty:
+            with telemetry.span("serve.idle"):
+                return self._queue.get(timeout=self._idle_s)
+
     def _ingest_loop(self) -> None:
         try:
             prev_token = None
             idle_since: float | None = None
+            chunk_id = 0
             while True:
                 try:
-                    # no idle action configured ⇒ None blocks forever —
-                    # the plain path
-                    item = self._queue.get(timeout=self._idle_s)
+                    item = self._next_item()
                 except queue.Empty:
                     # idle window: nothing arrived for _idle_s. Let the
                     # device finish the last batch, then run whichever
@@ -404,46 +415,54 @@ class PartitionService:
                                 and self._part.maybe_shrink()):
                             self._idle_shrinks += 1
                     continue
+                dequeued = time.perf_counter()
                 idle_since = None
                 if item is _STOP:
                     break
+                b = self._batches
                 # double buffering: coerce the first chunk while the
                 # device still executes the previous batch (async
                 # dispatch keeps running under this host work)...
-                t0 = time.perf_counter()
-                p = self._part.prepare(item[0])
-                prepared, records = [p], [(item[1], p.num_events)]
-                total, stopped = p.num_events, False
-                self._coercion_s += time.perf_counter() - t0
+                with telemetry.span("serve.coerce", batch=b) as sp:
+                    p = self._part.prepare(item[0])
+                self._coercion_s += time.perf_counter() - sp.start
+                records = [(chunk_id, item[1], item[2], dequeued,
+                            p.num_events)]
+                chunk_id += 1
+                prepared, total, stopped = [p], p.num_events, False
                 # ...then wait for that batch's completion token — the
                 # slot-loop beat during which further arrivals pile up
                 # in the queue...
                 if prev_token is not None:
-                    t0 = time.perf_counter()
-                    jax.block_until_ready(prev_token)
-                    self._device_wait_s += time.perf_counter() - t0
+                    with telemetry.span("serve.wait_prev", batch=b) as sp:
+                        jax.block_until_ready(prev_token)
+                    self._device_wait_s += time.perf_counter() - sp.start
                 # ...and only now drain them: everything that accumulated
                 # while the device ran coalesces into ONE dispatch
                 # (continuous batching, bounded by max_batch_events).
                 # Draining before the wait would sample the queue at its
                 # emptiest and defeat the coalescing.
-                t0 = time.perf_counter()
-                while self.max_batch_events is None \
-                        or total < self.max_batch_events:
-                    try:
-                        nxt = self._queue.get_nowait()
-                    except queue.Empty:
-                        break
-                    if nxt is _STOP:
-                        stopped = True
-                        break
-                    p = self._part.prepare(nxt[0])
-                    prepared.append(p)
-                    records.append((nxt[1], p.num_events))
-                    total += p.num_events
-                batch = _merge_prepared(prepared)
-                self._coercion_s += time.perf_counter() - t0
-                with self._lock:
+                with telemetry.span("serve.coerce", batch=b) as sp:
+                    while self.max_batch_events is None \
+                            or total < self.max_batch_events:
+                        try:
+                            nxt = self._queue.get_nowait()
+                        except queue.Empty:
+                            break
+                        dequeued = time.perf_counter()
+                        if nxt is _STOP:
+                            stopped = True
+                            break
+                        p = self._part.prepare(nxt[0])
+                        prepared.append(p)
+                        records.append((chunk_id, nxt[1], nxt[2], dequeued,
+                                        p.num_events))
+                        chunk_id += 1
+                        total += p.num_events
+                    batch = _merge_prepared(prepared)
+                self._coercion_s += time.perf_counter() - sp.start
+                with self._lock, telemetry.span("serve.dispatch", batch=b,
+                                                events=total):
                     self._part.feed_prepared(batch)
                     # completion token: a DERIVED scalar (not a raw state
                     # leaf — the next feed donates the state's buffers,
@@ -451,7 +470,7 @@ class PartitionService:
                     # under the lock, so it is ordered before any later
                     # donation of its input.
                     token = jnp.add(self._part.state.cut_edges, 0)
-                self._inflight.put((token, time.perf_counter(), records))
+                self._inflight.put((token, b, time.perf_counter(), records))
                 prev_token = token
                 self._batches += 1
                 if stopped:
@@ -463,26 +482,30 @@ class PartitionService:
 
     def _completion_loop(self) -> None:
         """Blocks on each batch's completion token in dispatch order,
-        stamping completion times for the latency percentiles and the
-        device-busy accounting. Runs off the ingest path so waiting for
-        chunk *t* never delays coercion of chunk *t+1*."""
+        stamping completion times for the latency percentiles and logging
+        each chunk's life as a ``serve.chunk`` record: its ``due`` time
+        (the caller's ``arrival``), ``submitted``, ``dequeued`` by the
+        ingest thread, its batch ``dispatched`` and ``committed``. Runs
+        off the ingest path so waiting for chunk *t* never delays
+        coercion of chunk *t+1*."""
         try:
-            last_done = None
             while True:
                 item = self._inflight.get()
                 if item is _STOP:
                     break
-                token, dispatch_t, records = item
-                jax.block_until_ready(token)
+                token, b, dispatched, records = item
+                with telemetry.span("serve.commit_wait", batch=b):
+                    jax.block_until_ready(token)
                 now = time.perf_counter()
-                busy_from = dispatch_t if last_done is None \
-                    else max(dispatch_t, last_done)
-                self._device_busy_s += max(now - busy_from, 0.0)
-                last_done = now
                 self._t_last_done = now
+                for chunk, due, submitted, dequeued, _ in records:
+                    telemetry.record(
+                        "serve.chunk", due, now, chunk=chunk, batch=b,
+                        due=due, submitted=submitted, dequeued=dequeued,
+                        dispatched=dispatched, committed=now)
                 with self._cond:
-                    for arrival, n_ev in records:
-                        self._latencies.append(now - arrival)
+                    for _, due, _, _, n_ev in records:
+                        self._latencies.append(now - due)
                         self._completed += 1
                         self._events_ingested_done += n_ev
                     self._cond.notify_all()
@@ -562,8 +585,7 @@ class PartitionService:
         ``batches_dispatched`` (post-coalescing), ``coercion_s`` (host
         prepare+merge time), ``device_wait_s`` (ingest thread blocked on
         the previous batch), ``submit_blocked_s`` (callers blocked on
-        backpressure), ``device_busy_fraction`` (fraction of the serving
-        wall with a batch executing), ``events_per_s`` (completed events
+        backpressure), ``events_per_s`` (completed events
         over the serving wall), and ``feed_p50_ms`` / ``feed_p99_ms``
         (submit-arrival → batch-completion latency percentiles). A query
         point: blocks on in-flight state scalars, never stalls ingest."""
@@ -598,8 +620,6 @@ class PartitionService:
             wall = max((end or time.perf_counter()) - self._t_start, 1e-9)
         m["wall_s"] = wall if wall is not None else 0.0
         m["events_per_s"] = (done / wall) if wall else 0.0
-        m["device_busy_fraction"] = (
-            min(self._device_busy_s / wall, 1.0) if wall else 0.0)
         m["feed_p50_ms"] = float(np.percentile(lat, 50) * 1e3) \
             if lat.size else None
         m["feed_p99_ms"] = float(np.percentile(lat, 99) * 1e3) \

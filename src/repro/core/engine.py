@@ -25,8 +25,8 @@ the semantic reference. For the same reason it is deliberately OUTSIDE the
 the fused_chooser window loop) attach to the windowed paths only, and
 their bit-identity gates all compare against this scan — a session on
 ``engine="scan"`` (or its small-tail fallback) therefore always scores
-with XLA gathers, counted as ``fallback_windows`` in
-``Partitioner.metrics()``. The carried ``PartitionState`` includes the
+with XLA gathers, counted under ``"scan"`` in
+``Partitioner.metrics()["windows"]``. The carried ``PartitionState`` includes the
 incremental pairwise ``cut_matrix`` (see the transition-module docstring
 for its invariant), so autoscale scale-ins here — like everywhere — merge
 cuts in O(K²) with no adjacency recompute.

@@ -122,18 +122,20 @@ def _run_window_adds(
     base_key = state.key
     kn = tx.make_knobs(cfg, n)
     choose = tx.make_chooser(cfg.balance_guard, policy)
-    is_add = vs >= 0
-    safe_vs = jnp.where(is_add, vs, 0)
+    with jax.named_scope("window.prep"):
+        is_add = vs >= 0
+        safe_vs = jnp.where(is_add, vs, 0)
 
-    sfn = score_fn or committed_scores
-    scores_c, deg_c = sfn(state, rows)                       # (W,K), (W,)
-    # window-position lookup for intra-window neighbour fixup
-    # (pad slots scatter to sentinel row n so they never clobber a vertex)
-    pos_of = jnp.full((n + 1,), -1, jnp.int32).at[
-        jnp.where(is_add, vs, n)
-    ].set(jnp.arange(w, dtype=jnp.int32))
-    valid = rows >= 0
-    win_pos = jnp.where(valid, pos_of[jnp.where(valid, rows, 0)], -1)  # (W,D)
+        sfn = score_fn or committed_scores
+        scores_c, deg_c = sfn(state, rows)                   # (W,K), (W,)
+        # window-position lookup for intra-window neighbour fixup (pad
+        # slots scatter to sentinel row n so they never clobber a vertex)
+        pos_of = jnp.full((n + 1,), -1, jnp.int32).at[
+            jnp.where(is_add, vs, n)
+        ].set(jnp.arange(w, dtype=jnp.int32))
+        valid = rows >= 0
+        win_pos = jnp.where(valid, pos_of[jnp.where(valid, rows, 0)],
+                            -1)                              # (W,D)
 
     def fix_step(carry, i):
         small, w_assign = carry
@@ -164,21 +166,23 @@ def _run_window_adds(
 
     small0 = _small(state)
     w_assign0 = jnp.full((w,), -1, jnp.int32)
-    (small, w_assign), _ = jax.lax.scan(
-        fix_step, (small0, w_assign0), jnp.arange(w, dtype=jnp.int32)
-    )
+    with jax.named_scope("window.slot_loop"):
+        (small, w_assign), _ = jax.lax.scan(
+            fix_step, (small0, w_assign0), jnp.arange(w, dtype=jnp.int32)
+        )
 
-    fresh = is_add & (w_assign >= 0)
-    # scatter target: non-fresh slots (pads, duplicate adds) go to the
-    # out-of-bounds row n, which jax scatters DROP — they must not write,
-    # or a pad could clobber a real vertex's slot (duplicate .set indices
-    # have undefined winners).
-    tgt = jnp.where(fresh, safe_vs, n)
-    assignment = state.assignment.at[tgt].set(
-        jnp.where(fresh, w_assign, -1), mode="drop")
-    present = state.present.at[tgt].set(True, mode="drop")
-    adj = state.adj.at[tgt].set(
-        jnp.where(fresh[:, None], rows, -1), mode="drop")
+    with jax.named_scope("window.apply"):
+        fresh = is_add & (w_assign >= 0)
+        # scatter target: non-fresh slots (pads, duplicate adds) go to the
+        # out-of-bounds row n, which jax scatters DROP — they must not
+        # write, or a pad could clobber a real vertex's slot (duplicate
+        # .set indices have undefined winners).
+        tgt = jnp.where(fresh, safe_vs, n)
+        assignment = state.assignment.at[tgt].set(
+            jnp.where(fresh, w_assign, -1), mode="drop")
+        present = state.present.at[tgt].set(True, mode="drop")
+        adj = state.adj.at[tgt].set(
+            jnp.where(fresh[:, None], rows, -1), mode="drop")
     return state._replace(
         assignment=assignment, present=present, adj=adj,
         active=small.active, edge_load=small.edge_load,
@@ -271,15 +275,16 @@ def _window_mixed_lane(
     k_max = state.edge_load.shape[0]
     base_key = state.key
 
-    ets = jnp.where(vs >= 0, ets, EVENT_PAD)
-    is_add = ets == EVENT_ADD
-    is_dv = ets == EVENT_DEL_VERTEX
-    is_de = ets == EVENT_DEL_EDGE
-    safe_vs = jnp.where(vs >= 0, vs, 0)
+    with jax.named_scope("window.prep"):
+        ets = jnp.where(vs >= 0, ets, EVENT_PAD)
+        is_add = ets == EVENT_ADD
+        is_dv = ets == EVENT_DEL_VERTEX
+        is_de = ets == EVENT_DEL_EDGE
+        safe_vs = jnp.where(vs >= 0, vs, 0)
 
-    rows_add = jnp.where(is_add[:, None], rows, -1)
+        rows_add = jnp.where(is_add[:, None], rows, -1)
 
-    arange_k = jnp.arange(k_max, dtype=jnp.int32)
+        arange_k = jnp.arange(k_max, dtype=jnp.int32)
 
     def onehot_sum(labels):
         return jnp.sum(labels[:, None] == arange_k, axis=0, dtype=jnp.int32)
@@ -370,13 +375,17 @@ def _window_mixed_lane(
         return (small, label_now, adj), None
 
     small0 = _small(state)
-    label_now0 = jnp.where(state.present, state.assignment, -1)
-    (small, label_now, adj), _ = jax.lax.scan(
-        step, (small0, label_now0, state.adj),
-        jnp.arange(w, dtype=jnp.int32),
-    )
+    with jax.named_scope("window.prep"):
+        label_now0 = jnp.where(state.present, state.assignment, -1)
+    with jax.named_scope("window.slot_loop"):
+        (small, label_now, adj), _ = jax.lax.scan(
+            step, (small0, label_now0, state.adj),
+            jnp.arange(w, dtype=jnp.int32),
+        )
+    with jax.named_scope("window.apply"):
+        present = label_now >= 0
     return state._replace(
-        assignment=label_now, present=label_now >= 0, adj=adj,
+        assignment=label_now, present=present, adj=adj,
         active=small.active, edge_load=small.edge_load,
         vertex_count=small.vertex_count, num_partitions=small.num_partitions,
         total_edges=small.total_edges, cut_edges=small.cut_edges,
@@ -516,8 +525,8 @@ def run_stream_windowed(
     (``repro.kernels.common.default_interpret``). The per-event scan
     engine (``repro.core.engine.run_stream``) remains pure XLA — it is
     the faithful reference the kernels are verified against; session
-    callers see the split in ``Partitioner.metrics()``
-    (``kernel_windows`` vs ``fallback_windows``).
+    callers see the split in ``Partitioner.metrics()["windows"]``, a
+    count of program calls per path.
     """
     cfg = cfg or EngineConfig()
     geom = resolve_geometry(stream, cfg, geometry)

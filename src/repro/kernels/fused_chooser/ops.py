@@ -133,29 +133,34 @@ def _fused_lane(
     n = state.assignment.shape[0]
     w = vs.shape[0]
     k_max = state.edge_load.shape[0]
-    prep = _prepare_window(state, ets, vs, rows)
-    rand_tab = tx.rand_index_table(state.key, t0, w, k_max)
-    scalars = jnp.stack([
-        state.num_partitions, state.total_edges, state.cut_edges,
-        state.denied_scaleout, state.scale_events])
+    with jax.named_scope("window.prep"):
+        prep = _prepare_window(state, ets, vs, rows)
+        rand_tab = tx.rand_index_table(state.key, t0, w, k_max)
+        scalars = jnp.stack([
+            state.num_partitions, state.total_edges, state.cut_edges,
+            state.denied_scaleout, state.scale_events])
     call = fused_window_choose if variant == "pallas" else \
         fused_window_choose_ref
     kwargs = {} if variant == "ref" else {"interpret": interpret}
-    w_label, _psel, remap, active, loads, cut_matrix, scal = call(
-        prep.ev, prep.src_lbl, prep.touch, rand_tab,
-        state.active, state.edge_load, state.vertex_count, state.cut_matrix,
-        scalars, knobs, flags, n=n, policy=policy,
-        balance_guard=balance_guard, autoscaling=autoscaling,
-        dynamic=dynamic, **kwargs)
+    with jax.named_scope("window.slot_loop"):
+        w_label, _psel, remap, active, loads, cut_matrix, scal = call(
+            prep.ev, prep.src_lbl, prep.touch, rand_tab,
+            state.active, state.edge_load, state.vertex_count,
+            state.cut_matrix, scalars, knobs, flags, n=n, policy=policy,
+            balance_guard=balance_guard, autoscaling=autoscaling,
+            dynamic=dynamic, **kwargs)
 
     # apply: rebuild the journal from the window-local decisions — two
     # O(n) gathers, no scatter ordering to get wrong
-    lbl_touched = w_label[jnp.clip(prep.last_touch, 0, w - 1)]
-    lbl_kept = jnp.where(prep.label0 >= 0,
-                         remap[jnp.maximum(prep.label0, 0)], -1)
-    label_final = jnp.where(prep.last_touch >= 0, lbl_touched, lbl_kept)
+    with jax.named_scope("window.apply"):
+        lbl_touched = w_label[jnp.clip(prep.last_touch, 0, w - 1)]
+        lbl_kept = jnp.where(prep.label0 >= 0,
+                             remap[jnp.maximum(prep.label0, 0)], -1)
+        label_final = jnp.where(prep.last_touch >= 0, lbl_touched,
+                                lbl_kept)
+        present = label_final >= 0
     return state._replace(
-        assignment=label_final, present=label_final >= 0, adj=prep.adj,
+        assignment=label_final, present=present, adj=prep.adj,
         active=active != 0, edge_load=loads[0], vertex_count=loads[1],
         num_partitions=scal[fk.SCAL_NP], total_edges=scal[fk.SCAL_TOTAL],
         cut_edges=scal[fk.SCAL_CUT], denied_scaleout=scal[fk.SCAL_DENIED],

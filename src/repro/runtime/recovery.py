@@ -51,7 +51,9 @@ from typing import NamedTuple
 
 import numpy as np
 
-from repro.api.partitioner import Partitioner, PreparedChunk
+# the module rather than its names: it imports this package's telemetry,
+# so it may still be loading when this module is imported
+from repro.api import partitioner as _partitioner
 from repro.core.config import EngineConfig
 from repro.core.geometry import Geometry
 
@@ -182,7 +184,7 @@ class RecoverableSession:
         but before feeding (the worst-ordered crash point).
     """
 
-    def __init__(self, part: Partitioner, directory: str, *,
+    def __init__(self, part: _partitioner.Partitioner, directory: str, *,
                  snapshot_every: int = 2048, keep: int = 3,
                  inject_crash_after: int | None = None):
         if snapshot_every <= 0:
@@ -200,10 +202,11 @@ class RecoverableSession:
 
     # -- the Partitioner protocol (what PartitionService drives) ------------
 
-    def prepare(self, events) -> PreparedChunk:
+    def prepare(self, events) -> _partitioner.PreparedChunk:
         return self.part.prepare(events)
 
-    def feed_prepared(self, chunk: PreparedChunk) -> "RecoverableSession":
+    def feed_prepared(self, chunk: _partitioner.PreparedChunk) \
+            -> "RecoverableSession":
         if chunk.num_events:
             self.journal.append(self.part.cursor, chunk.etype,
                                 chunk.vertex, chunk.nbrs)
@@ -339,7 +342,7 @@ class RecoverableSession:
         cursors. Chop-invariance + cursor-keyed RNG make the result
         bit-identical to the run that never crashed. ``**kw`` are the
         session knobs (policy, window, …) — they are not checkpointed."""
-        part = Partitioner.restore(directory, cfg, **kw)
+        part = _partitioner.Partitioner.restore(directory, cfg, **kw)
         sess = cls(part, directory, snapshot_every=snapshot_every,
                    keep=keep)
         entries = sess.journal.entries()

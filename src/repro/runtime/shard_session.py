@@ -150,7 +150,8 @@ def _sharded_window(state: PartitionState, ets, vs, rows, t0,
 
     (adj_loc, _), em = jax.lax.scan(
         step, (state.adj, state.present), jnp.arange(w, dtype=i32))
-    rows_dv2, fresh_c, was_c, e1_c, e2_c = jax.lax.psum(em, AXIS)
+    with jax.named_scope("window.psum_emit"):
+        rows_dv2, fresh_c, was_c, e1_c, e2_c = jax.lax.psum(em, AXIS)
     fresh = fresh_c != 0
     was = was_c != 0
     exists = is_de & (e1_c != 0) & (e2_c != 0)
@@ -166,7 +167,8 @@ def _sharded_window(state: PartitionState, ets, vs, rows, t0,
         jnp.where(owned(safe_vs), label0_loc[loc(safe_vs)] + 2, 0),
         jnp.where(owned(us), label0_loc[loc(us)] + 2, 0),
     )
-    sl2, l0v2, l0u2 = jax.lax.psum(contrib, AXIS)
+    with jax.named_scope("window.psum_halo"):
+        sl2, l0v2, l0u2 = jax.lax.psum(contrib, AXIS)
     src_lbl = jnp.where(src_row >= 0, sl2 - 2, -1)
 
     # touch tables: replicated recompute. The dense scan reads

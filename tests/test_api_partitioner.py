@@ -4,6 +4,7 @@ whole-stream ``run_stream`` no matter how the stream is chopped across
 events landing exactly on a boundary) and across ``snapshot()`` →
 ``restore()`` → ``feed(rest)``."""
 import os
+import time
 
 import numpy as np
 import pytest
@@ -14,6 +15,7 @@ from repro.core import EngineConfig, run_stream
 from repro.core.state import PartitionState
 from repro.graph.generators import make_graph
 from repro.graph import stream as gstream
+from repro.runtime import telemetry
 
 
 def _churn_fixture():
@@ -242,3 +244,68 @@ def test_empty_feed_is_noop():
     part.feed((s.etype[:0], s.vertex[:0], s.nbrs[:0]))
     assert part.cursor == 0
     assert part.trace().cut_edges.shape == (0,)
+
+
+# -- what a feed records: one dispatch span per program call ------------------
+
+def _expected_dispatches(s, engine, kernel, *, window, chunk):
+    """(path, events, slots) of each program call ``_feed_chunked`` makes,
+    by the session's documented chopping: full windows, then a tail that
+    the per-event scan takes under "auto" and a padded window under
+    "windowed"; "scan" takes each call whole."""
+    out = []
+    for a in range(0, s.num_events, chunk):
+        b = min(a + chunk, s.num_events)
+        t = a
+        while t < b:
+            e = min(t + window, b)
+            if engine == "scan" or (engine == "auto" and e - t < window):
+                out.append(("scan", b - t, b - t))
+                break
+            kind = "adds" if np.all(s.etype[t:e] == gstream.EVENT_ADD) \
+                else "mixed"
+            out.append((kind + ("_kernel" if kernel else ""), e - t, window))
+            t = e
+    return out
+
+
+@pytest.mark.parametrize("engine, kernel", [("auto", False),
+                                            ("scan", False),
+                                            ("windowed", False),
+                                            ("windowed", True)])
+def test_one_dispatch_span_per_program_call(engine, kernel):
+    s, cfg = _churn_fixture()
+    part = Partitioner.from_stream(s, cfg, seed=0, engine=engine, window=32,
+                                   use_kernel=kernel)
+    t0 = time.perf_counter()
+    _feed_chunked(part, s, 50)
+    recs = telemetry.spans(since=t0)
+    got = [(r.attrs["path"], r.attrs["events"], r.attrs["slots"])
+           for r in recs if r.name == "session.dispatch"]
+    want = _expected_dispatches(s, engine, kernel, window=32, chunk=50)
+    assert got == want
+    assert {r.parent for r in recs if r.name == "session.dispatch"} \
+        == {"session.feed"}
+    assert len([r for r in recs if r.name == "session.feed"]) == 3
+    m = part.metrics()
+    assert sum(m["windows"].values()) == len(got)
+    for path, count in m["windows"].items():
+        assert count == sum(p == path for p, _, _ in got), path
+    assert m["pad_slots"] == sum(sl - ev for _, ev, sl in got)
+    _identical(run_stream(s, policy="sdp", cfg=cfg, seed=0)[0], part.state)
+
+
+def test_pad_slots_of_a_known_chopping():
+    """300 events at window 256 on engine="windowed": one full window and
+    one of 44 events padded with 212 no-op slots."""
+    g = make_graph("social", 400, 1200, seed=2)
+    s = gstream.interleaved_churn(g, warmup_frac=0.2, del_every=3,
+                                  edge_del_every=5, seed=4)
+    part = Partitioner.from_stream(s, EngineConfig(k_max=8, k_init=1,
+                                                   max_cap=400),
+                                   seed=0, engine="windowed", window=256)
+    part.feed((s.etype[:300], s.vertex[:300], s.nbrs[:300]))
+    m = part.metrics()
+    assert m["pad_slots"] == 212
+    assert sum(m["windows"].values()) == 2
+    assert m["windows"]["scan"] == 0

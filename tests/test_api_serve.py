@@ -12,6 +12,7 @@ import pytest
 from repro.api import Partitioner, PartitionService, PreparedChunk
 from repro.core import run_stream
 from repro.graph import stream as gstream
+from repro.runtime import telemetry
 
 from tests.test_api_partitioner import _churn_fixture, _identical
 
@@ -188,6 +189,7 @@ def test_route_semantics_and_input_forms():
 def test_metrics_counters_and_lifecycle():
     s, cfg = _churn_fixture()
     chunks = _chunks(s, 13)
+    t0 = time.perf_counter()
     svc = PartitionService(_session(s, cfg), max_pending_chunks=8)
     for c in chunks:
         svc.submit(c)
@@ -198,7 +200,12 @@ def test_metrics_counters_and_lifecycle():
     assert 1 <= m["batches_dispatched"] <= len(chunks)
     assert m["queue_depth"] == 0
     assert m["chunks_dropped"] == 0
-    assert 0.0 <= m["device_busy_fraction"] <= 1.0
+    # each chunk's life, stamp by stamp, from its due time to its commit
+    lives = [r.attrs for r in telemetry.spans(since=t0)
+             if r.name == "serve.chunk"]
+    assert len(lives) == len(chunks)
+    assert all(c["due"] <= c["submitted"] <= c["dequeued"]
+               <= c["dispatched"] <= c["committed"] for c in lives)
     assert m["feed_p50_ms"] is not None and m["feed_p99_ms"] is not None
     assert m["feed_p50_ms"] <= m["feed_p99_ms"] + 1e-9
     assert m["events_per_s"] > 0
@@ -214,6 +221,38 @@ def test_metrics_counters_and_lifecycle():
     # queries outlive close()
     assert svc.where(0) in (-1, *range(cfg.k_max))
     assert "closed=True" in repr(svc)
+
+
+def test_chunk_stages_add_up_to_latencies():
+    """For every chunk, lag (submitted - due) + queued + held + in flight
+    is its ``latencies()`` entry; chunk ids are dense in submission
+    order and batch ids increase with them."""
+    s, cfg = _churn_fixture()
+    chunks = _chunks(s, 13)
+    t0 = time.perf_counter()
+    svc = PartitionService(_session(s, cfg), max_pending_chunks=len(chunks),
+                           autostart=False)
+    for i, c in enumerate(chunks[:4]):     # staged: one batch takes them
+        svc.submit(c, arrival=time.perf_counter() - 0.001 * i)
+    svc.start()
+    for c in chunks[4:]:
+        svc.submit(c, arrival=time.perf_counter() - 0.002)
+    svc.flush()
+    lat = svc.latencies()
+    svc.close()
+    lives = sorted((r.attrs for r in telemetry.spans()
+                    if r.name == "serve.chunk" and r.attrs["submitted"] >= t0),
+                   key=lambda a: a["chunk"])
+    assert [a["chunk"] for a in lives] == list(range(len(chunks)))
+    batches = [a["batch"] for a in lives]
+    assert batches == sorted(batches) and batches[:4] == [0, 0, 0, 0]
+    assert batches[-1] == svc.metrics()["batches_dispatched"] - 1
+    for a in lives:
+        stages = (a["submitted"] - a["due"], a["dequeued"] - a["submitted"],
+                  a["dispatched"] - a["dequeued"],
+                  a["committed"] - a["dispatched"])
+        assert min(stages) >= 0
+        assert sum(stages) == pytest.approx(lat[a["chunk"]], abs=1e-6)
 
 
 def test_constructor_validation_and_flush_guard():

@@ -188,13 +188,14 @@ def test_partitioner_use_kernel_parity_and_coverage():
         p.feed((s.etype[sl], s.vertex[sl], s.nbrs[sl]))
         t = sl.stop
     _identical(ref, p.state)
-    m = p.metrics()
-    assert m["kernel_windows"] > 0
-    assert m["fallback_windows"] > 0          # the 100-event calls leave tails
+    m = p.metrics()["windows"]
+    assert m["mixed_kernel"] + m["adds_kernel"] > 0
+    assert m["scan"] > 0                      # the 100-event calls leave tails
     q = Partitioner(cfg, n=s.n, max_deg=s.max_deg, policy="sdp", seed=0,
                     window=32)
     q.feed(s)
-    assert q.metrics()["kernel_windows"] == 0  # default surface: all XLA
+    m = q.metrics()["windows"]
+    assert m["mixed_kernel"] + m["adds_kernel"] == 0  # default: all XLA
     _identical(ref, q.state)
 
 

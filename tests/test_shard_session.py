@@ -145,6 +145,9 @@ def test_sharded_session_chop_and_grow():
     m = shard.metrics()
     assert m["shard_devices"] == jax.device_count()
     assert m["per_device_state_bytes"] > 0
+    # every slice rode the sharded window, its tail padded to W slots
+    assert sum(m["windows"].values()) == m["windows"]["sharded"]
+    assert m["pad_slots"] == m["windows"]["sharded"] * W - s.num_events
 
 
 @multi_device
