@@ -3,10 +3,11 @@
 
     python scripts/mem_probe.py
 
-The compiler reports a few GB of temp for the session's mixed-window
-program (``Partitioner``'s donated ``_run_window_mixed``), while the
-runtime's ``peak_bytes_in_use`` after a session stays near the state's
-size. This probe separates the readings, on one chip, in one process:
+The session's mixed-window program (``Partitioner``'s donated
+``_run_window_mixed``, with ``adj`` pinned row-major) is compiled with
+its temp reported, while the runtime's ``peak_bytes_in_use`` after a
+session counts only buffers. This probe separates the readings, on one
+chip, in one process:
 
 1. ``memory_analysis()`` of the program at n = 2**22, max_deg = 192,
    K = 16, W = 256: argument, temp, alias and output bytes.
@@ -16,8 +17,9 @@ size. This probe separates the readings, on one chip, in one process:
    the state and half the reported temp. If the temp is allocated when
    the program runs, this run fails for memory; if it runs, the temp is
    not held at once.
-4. The program compiled at n = 10,000,000, which the compiler is
-   expected to refuse.
+4. The program compiled at n = 10,000,000: about 10.3 GB of state
+   (1,024 B a vertex of row-major ``adj``), which fits one chip now
+   that no second ``adj`` is held as temp.
 
 It exits non-zero without a TPU.
 """
@@ -35,7 +37,11 @@ def main() -> int:
     import jax
     import jax.numpy as jnp
 
-    from repro.api.partitioner import _mixed_donated
+    from jax.sharding import SingleDeviceSharding
+
+    from repro.api.partitioner import (
+        _init_pinned, _mixed_donated, adj_format,
+    )
     from repro.core.config import EngineConfig
     from repro.core.state import init_state
     from repro.graph.stream import powerlaw_churn
@@ -45,6 +51,7 @@ def main() -> int:
         print(f"mem_probe: no TPU (JAX found {dev.platform})", file=sys.stderr)
         return 1
     cfg = EngineConfig(k_max=K, k_init=1, autoscale=True, max_cap=3000)
+    fmt = adj_format(SingleDeviceSharding(dev))
     limit = dev.memory_stats()["bytes_limit"]
     print(f"device {dev.device_kind} bytes_limit={limit}", flush=True)
 
@@ -57,7 +64,7 @@ def main() -> int:
               jax.ShapeDtypeStruct((W,), jnp.int32),
               jax.ShapeDtypeStruct((W, MAX_DEG), jnp.int32),
               jax.ShapeDtypeStruct((), jnp.int32))
-        return _mixed_donated.lower(st, *ev, policy="sdp", cfg=cfg).compile()
+        return _mixed_donated(fmt, "sdp", cfg).lower(st, *ev).compile()
 
     n = 1 << 22
     exe = compiled(n)
@@ -70,7 +77,7 @@ def main() -> int:
     s = powerlaw_churn(n, W, max_deg=MAX_DEG, seed=0)
     ev = (jnp.asarray(s.etype), jnp.asarray(s.vertex), jnp.asarray(s.nbrs),
           jnp.int32(0))
-    state = jax.jit(lambda: init_state(n, MAX_DEG, K, 1, 0))()
+    state = _init_pinned(n, MAX_DEG, K, 1, fmt)(jax.random.PRNGKey(0))
     jax.block_until_ready(state)
     p0 = peak()
     state = exe(state, *ev)

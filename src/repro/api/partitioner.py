@@ -21,9 +21,12 @@ Guarantees:
   tests/test_api_partitioner.py.
 * **Donated carry.** The session's state is donated to each feed call's
   jitted kernel, so XLA reuses the O(n·max_deg) adjacency buffers
-  between calls instead of copying them. Corollary: a reference you took
-  from ``part.state`` is invalidated by the *next* ``feed()`` — copy
-  (``np.asarray``) anything you want to keep, or use ``snapshot()``.
+  between calls instead of copying them. A dense session keeps the
+  adjacency in the row-major device layout its programs take
+  (``adj_format``), so no call moves it between layouts either.
+  Corollary: a reference you took from ``part.state`` is invalidated by
+  the *next* ``feed()`` — copy (``np.asarray``) anything you want to
+  keep, or use ``snapshot()``.
 * **Auto engine selection.** Per call, full windows of ``window`` events
   ride the batched mixed-window kernel (``run_window_mixed``, or the
   small-carry ``run_window_adds`` for pure-ADD windows) and small tails
@@ -68,11 +71,16 @@ carries).
 """
 from __future__ import annotations
 
+import functools
+import math
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax._src import config as jax_config
+from jax.experimental.layout import Format, Layout
+from jax.sharding import SingleDeviceSharding
 
 from repro.checkpoint.manager import CheckpointManager
 from repro.core import engine as eng
@@ -103,29 +111,101 @@ _ENGINES = ("auto", "scan", "windowed")
 # their Pallas forms under use_kernel), and the vertex-sharded window
 _PATHS = ("scan", "adds", "mixed", "adds_kernel", "mixed_kernel", "sharded")
 
-# Donated re-jits of the engine kernels: the session immediately rebinds
-# its carried state to each call's result, so donation lets XLA reuse the
-# (n, max_deg) adjacency (and (k_max, k_max) cut_matrix) buffers between
-# feed() calls instead of copying them per call.
-_scan_donated = jax.jit(
-    eng._run_events, static_argnames=("policy", "cfg"), donate_argnums=(0,))
-_adds_donated = jax.jit(
-    wnd._run_window_adds, static_argnames=("policy", "cfg", "score_fn"),
-    donate_argnums=(0,))
-_mixed_donated = jax.jit(
-    wnd._run_window_mixed, static_argnames=("policy", "cfg"),
-    donate_argnums=(0,))
+# The dense session keeps ``state.adj`` in ONE device format, row-major,
+# the layout its programs read and write whole rows in. A TPU's default
+# for an (n, max_deg) int32 array is column-major (a row-major 192-lane row
+# pads to 256), and a program bound with the default would move the whole
+# adjacency to row-major on entry and back on exit: a copy each way, and
+# a second adjacency as temp. So every session program pins ``adj`` to
+# ``adj_format`` on input and output, ``init_state`` builds it there, and
+# ``Partitioner._pin`` re-places a state any other step produced. On a CPU
+# row-major is the default and the pin changes nothing.
+ADJ_LAYOUT = Layout(major_to_minor=(0, 1))   # tiling left to the compiler
 
 
-def _mixed_fused_donated():
-    """Donated re-jit of the fused Pallas mixed-window kernel — imported
-    lazily so sessions that never set ``use_kernel=True`` do not import
-    the kernels layer at all."""
+def adj_format(sharding) -> Format:
+    """The device format of a dense session's ``adj`` on ``sharding``."""
+    return Format(ADJ_LAYOUT, sharding)
+
+
+def _state_formats(fmt: Format) -> PartitionState:
+    """Placements of a dense state whose ``adj`` is in ``fmt``: every
+    other leaf on the same device in its default layout."""
+    s = fmt.sharding
+    return PartitionState(*[s] * len(PartitionState._fields))._replace(
+        adj=fmt)
+
+
+def _donated(body, fmt: Format, n_inputs: int, *, trace: bool = False,
+             **statics):
+    """``body(state, *inputs, **statics)`` jitted with the state donated
+    and ``adj`` in ``fmt`` on both sides, so donation aliases it in place
+    from window to window."""
+    st, s = _state_formats(fmt), fmt.sharding
+    return jax.jit(functools.partial(body, **statics),
+                   in_shardings=(st,) + (s,) * n_inputs,
+                   out_shardings=(st, s) if trace else st,
+                   donate_argnums=(0,))
+
+
+# The session's programs, one per (adj format, statics). The session
+# rebinds its carried state to each call's result, so donation lets XLA
+# reuse the (n, max_deg) adjacency (and (k_max, k_max) cut_matrix)
+# buffers between feed() calls instead of copying them per call.
+@functools.lru_cache(maxsize=None)
+def _scan_donated(fmt: Format, policy: str, cfg: EngineConfig):
+    return _donated(eng._run_events, fmt, 4, trace=True, policy=policy,
+                    cfg=cfg)
+
+
+@functools.lru_cache(maxsize=None)
+def _adds_donated(fmt: Format, policy: str, cfg: EngineConfig,
+                  score_fn=None):
+    return _donated(wnd._run_window_adds, fmt, 3, policy=policy, cfg=cfg,
+                    score_fn=score_fn)
+
+
+@functools.lru_cache(maxsize=None)
+def _mixed_donated(fmt: Format, policy: str, cfg: EngineConfig):
+    return _donated(wnd._run_window_mixed, fmt, 4, policy=policy, cfg=cfg)
+
+
+@functools.lru_cache(maxsize=None)
+def _mixed_fused_donated(fmt: Format, policy: str, cfg: EngineConfig,
+                         interpret: bool | None = None,
+                         variant: str = "pallas"):
+    """The fused Pallas mixed-window kernel — imported lazily so sessions
+    that never set ``use_kernel=True`` do not import the kernels layer at
+    all."""
     from repro.kernels.fused_chooser.ops import _run_window_mixed_fused
+    return _donated(_run_window_mixed_fused, fmt, 4, policy=policy, cfg=cfg,
+                    interpret=interpret, variant=variant)
+
+
+def _uncached(fn):
+    """``fn`` with the programs it compiles kept out of JAX's persistent
+    compilation cache. JAX 0.9 restores a cached executable without the
+    entry layouts it was compiled for: its outputs come back in the
+    default layout. So each process compiles the programs that pin
+    ``adj`` itself (a few seconds in all); no process writes them, so no
+    read finds them."""
+    @functools.wraps(fn)
+    def call(*args, **kwargs):
+        with jax_config.persistent_cache_min_compile_time_secs(math.inf):
+            return fn(*args, **kwargs)
+    return call
+
+
+@functools.lru_cache(maxsize=None)
+def _init_pinned(n: int, max_deg: int, k_max: int, k_init: int,
+                 fmt: Format):
+    """``init_state`` built straight into ``fmt``'s layout (the PRNG key,
+    drawn from the seed on the host, is its argument), so the adjacency
+    never exists in two layouts at once."""
     return jax.jit(
-        _run_window_mixed_fused,
-        static_argnames=("policy", "cfg", "interpret", "variant"),
-        donate_argnums=(0,))
+        lambda key: init_state(n, max_deg, k_max, k_init)._replace(key=key),
+        out_shardings=_state_formats(fmt))
+
 
 def _resolve_vertices_mesh(devices):
     """Constructor/``reshard`` device selection: None = every local
@@ -290,10 +370,8 @@ class Partitioner:
         if use_kernel:
             from repro.kernels.partition_affinity.ops import scores_for_state
             self._score_fn = scores_for_state
-            self._mixed_fn = _mixed_fused_donated()
         else:
             self._score_fn = None
-            self._mixed_fn = _mixed_donated
         if shrink_every <= 0:
             raise ValueError(
                 f"shrink_every={shrink_every} must be > 0: it is the "
@@ -342,8 +420,10 @@ class Partitioner:
         self._rebalance_events: list[dict] = []
         self._windows = dict.fromkeys(_PATHS, 0)
         self._pad_slots = 0
+        self._relayouts = 0
         self._sharded = bool(sharded)
         self._mesh = None
+        self._fmt = None
         if self._sharded:
             self._mesh = _resolve_vertices_mesh(shard_devices)
             # semantic geometry: the tier a dense session would sit at —
@@ -354,8 +434,10 @@ class Partitioner:
             self._state = init_sharded_state(
                 *self._sem_geom, cfg.k_init, seed, self._mesh)
         else:
-            self._state = init_state(int(n or 1), int(max_deg or 1),
-                                     cfg.k_max, cfg.k_init, seed)
+            self._bind(SingleDeviceSharding(jax.devices()[0]))
+            self._state = _uncached(_init_pinned(
+                int(n or 1), int(max_deg or 1), cfg.k_max, cfg.k_init,
+                self._fmt))(jax.random.PRNGKey(seed))
         self._regeometries = 0
         self._shrinks = 0
         self._compactions = 0
@@ -476,11 +558,11 @@ class Partitioner:
         if self._sharded:
             phys = Geometry(pad_rows(target.n, self._mesh.shape["vertices"]),
                             target.max_deg, target.k_max)
-            self._state = shard_state(
-                grow_state(unshard_state(self._state), phys), self._mesh)
+            self._state = self._pin(
+                grow_state(unshard_state(self._state), phys))
             self._sem_geom = target
         else:
-            self._state = grow_state(self._state, target)
+            self._state = self._pin(grow_state(self._state, target))
         self._regeometries += 1
         self._record_geometry("grow", cur, target)
 
@@ -507,7 +589,7 @@ class Partitioner:
             return
         _, prefix = live_extent(self._state)
         if prefix.n <= target.n and prefix.max_deg <= target.max_deg:
-            self._state = shrink_state(self._state, target)
+            state = shrink_state(self._state, target)
         else:
             if self.policy == "hash":
                 raise ValueError(
@@ -516,13 +598,13 @@ class Partitioner:
                     "decision — only id-preserving shrinks are legal "
                     "(shrink_to a geometry the current slot ids fit, or "
                     "accept the current tier)")
-            self._state, perm = compact_state(self._state, target)
+            state, perm = compact_state(self._state, target)
             self._apply_perm(perm)
         if self._sharded:
-            # repacks land dense at the semantic target — pad rows back
-            # to a mesh multiple and re-place on the vertices mesh
+            # repacks land dense at the semantic target; _pin pads the
+            # rows back to a mesh multiple
             self._sem_geom = target
-            self._state = shard_state(self._state, self._mesh)
+        self._state = self._pin(state)
         self._regeometries += 1
         if kind == "shrink":
             self._shrinks += 1
@@ -635,8 +717,8 @@ class Partitioner:
                 "reshard(devices=...) to move it onto a different mesh")
         self.sync()
         host = jax.tree_util.tree_map(np.asarray, self._state)
-        self._state = jax.tree_util.tree_map(
-            lambda a: jax.device_put(a, device), host)
+        self._bind(SingleDeviceSharding(device))
+        self._state = self._pin(jax.device_put(host, device))
         return self
 
     def reshard(self, devices=None) -> "Partitioner":
@@ -841,10 +923,9 @@ class Partitioner:
         # the scan backend is outside the kernel surface (it is the
         # faithful reference): its one program call carries no padding
         with self._dispatch("scan", len(et), len(et)):
-            self._state, tr = _scan_donated(
+            self._state, tr = self._scan_fn(
                 self._state, jnp.asarray(et), jnp.asarray(vx),
-                jnp.asarray(nb), jnp.int32(self._cursor),
-                policy=self.policy, cfg=self.cfg)
+                jnp.asarray(nb), jnp.int32(self._cursor))
         if self.collect_trace:
             self._traces.append(tr)
 
@@ -874,14 +955,40 @@ class Partitioner:
             rows_w = wnd._pad_to(nb, w, -1)
             t0 = jnp.int32(self._cursor)
             if adds:
-                self._state = _adds_donated(
-                    self._state, vs_w, rows_w, t0,
-                    policy=self.policy, cfg=self.cfg,
-                    score_fn=self._score_fn)
+                self._state = self._adds_fn(self._state, vs_w, rows_w, t0)
             else:
                 self._state = self._mixed_fn(
                     self._state, wnd._pad_to(et, w, EVENT_PAD),
-                    vs_w, rows_w, t0, policy=self.policy, cfg=self.cfg)
+                    vs_w, rows_w, t0)
+
+    def _bind(self, sharding) -> None:
+        """Bind the dense session's programs to ``sharding``'s device,
+        with ``adj`` in ``adj_format`` there."""
+        self._fmt = adj_format(sharding)
+        statics = (self._fmt, self.policy, self.cfg)
+        self._scan_fn = _uncached(_scan_donated(*statics))
+        self._adds_fn = _uncached(_adds_donated(*statics, self._score_fn))
+        self._mixed_fn = _uncached((_mixed_fused_donated if self.use_kernel
+                                    else _mixed_donated)(*statics))
+
+    def _pin(self, state: PartitionState) -> PartitionState:
+        """Place a state that a step outside the session's programs made
+        (a regeometry, a repack, a rebalance, a restore, a ``place``)
+        where those programs take it: a sharded session's on its vertices
+        mesh; a dense session's with ``adj`` in ``adj_format``, moved
+        only where its format differs. Each such move is a
+        ``session.relayout`` span and counts in ``metrics()["relayouts"]``."""
+        if self._sharded:
+            return shard_state(state, self._mesh)
+        adj = state.adj
+        if (adj.format.layout.major_to_minor
+                == self._fmt.layout.major_to_minor
+                and adj.sharding == self._fmt.sharding):
+            return state
+        with telemetry.span("session.relayout", bytes=adj.nbytes):
+            adj = _uncached(jax.device_put)(adj, self._fmt, donate=True)
+        self._relayouts += 1
+        return state._replace(adj=adj)
 
     def sync(self) -> "Partitioner":
         """Block until every dispatched feed has executed (feeds are
@@ -949,11 +1056,9 @@ class Partitioner:
             self._state, jnp.int32(self._cursor), jnp.float32(slack),
             jnp.float32(self.cfg.max_cap), True,
             m=min(m, self.n), passes=passes)
-        if self._sharded:
-            # the rebalance jit runs under GSPMD over the sharded inputs
-            # but commits to no particular output layout — re-pin the
-            # session's canonical vertices-mesh shardings
-            self._state = shard_state(self._state, self._mesh)
+        # the rebalance jit commits to no particular output layout (under
+        # GSPMD over a sharded state, to no sharding either)
+        self._state = self._pin(self._state)
         ev = {"cursor": self._cursor, "m": m, "passes": passes,
               "moved": int(stats.moved),
               "cut_before": int(stats.cut_before),
@@ -994,6 +1099,8 @@ class Partitioner:
         # the no-op slots that padded the windows among them
         m["windows"] = dict(self._windows)
         m["pad_slots"] = self._pad_slots
+        # moves of a dense session's adj into its programs' layout (_pin)
+        m["relayouts"] = self._relayouts
         m["rebalances"] = self._rebalances
         m["rebalance_moves"] = self._rebalance_moves
         m["rebalance_drift_fires"] = self._drift_fires
@@ -1147,13 +1254,13 @@ class Partitioner:
             # pre-cut_matrix checkpoint: fill_missing kept `like`'s zero
             # matrix — rebuild it exactly from the restored adjacency
             state = recount_cut_matrix(state)
-        part._state = grow_state(state, target)
+        state = grow_state(state, target)
         if part._sharded:
-            # re-place the restored canonical layout on the session's
+            # the restored canonical layout goes onto the session's
             # vertices mesh (rows re-padded to the new shard count — the
             # cross-layout round-trip: dense↔sharded, any mesh width)
-            part._sem_geom = geometry_of(part._state)
-            part._state = shard_state(part._state, part._mesh)
+            part._sem_geom = geometry_of(state)
+        part._state = part._pin(state)
         part._cursor = int(step)
         # the external-id map of a compacted session rides in the
         # checkpoint's extras — rebuild its dense inverse

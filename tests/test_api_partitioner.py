@@ -6,10 +6,13 @@ events landing exactly on a boundary) and across ``snapshot()`` →
 import os
 import time
 
+import jax
 import numpy as np
 import pytest
+from jax.experimental.layout import Layout
 
 from repro.api import Partitioner
+from repro.api import partitioner as part_api
 from repro.checkpoint.manager import CheckpointManager
 from repro.core import EngineConfig, run_stream
 from repro.core.state import PartitionState
@@ -309,3 +312,73 @@ def test_pad_slots_of_a_known_chopping():
     assert m["pad_slots"] == 212
     assert sum(m["windows"].values()) == 2
     assert m["windows"]["scan"] == 0
+
+
+# -- the layout the session keeps adj in --------------------------------------
+
+def _lifecycle(layout, directory):
+    """Run a session through every step that makes its state outside its
+    programs, with ``adj`` pinned to ``layout``; return the final state
+    and, per step, the moves of ``adj`` its ``session.relayout`` spans
+    and ``metrics()["relayouts"]`` agree on."""
+    s, cfg = _churn_fixture()
+    mid = s.num_events // 2
+
+    def feed(p, a, b, chunk):
+        for t in range(a, b, chunk):
+            e = min(t + chunk, b)
+            p.feed((s.etype[t:e], s.vertex[t:e], s.nbrs[t:e]))
+        return p
+
+    def then(p, _):
+        return p
+
+    steps = [  # each returns the session: a restore makes a new one
+        lambda p: feed(p, 0, mid, 40),                  # grows on demand
+        lambda p: p.grow_to(n=4 * p.n),
+        lambda p: p.compact(),
+        lambda p: then(p, p.rebalance(m=8, passes=1)),
+        lambda p: then(p, p.snapshot(directory)),
+        lambda p: Partitioner.restore(directory, cfg, window=32),
+        lambda p: feed(p, mid, s.num_events, 45),       # scan tails
+        lambda p: p.place(jax.devices()[0]),
+    ]
+    part = Partitioner(cfg, seed=0, window=32)
+    assert part.metrics()["relayouts"] == 0
+    moves = []
+    for fn in steps:
+        assert part.state.adj.format.layout.major_to_minor == layout
+        t = time.perf_counter()
+        before = part.metrics()["relayouts"]
+        nxt = fn(part)
+        if nxt is not part:
+            before, part = 0, nxt
+        recs = [r for r in telemetry.spans(since=t)
+                if r.name == "session.relayout"]
+        assert part.metrics()["relayouts"] - before == len(recs)
+        assert all(r.attrs["bytes"] == part.state.adj.nbytes for r in recs)
+        moves.append(len(recs))
+    assert part.state.adj.format.layout.major_to_minor == layout
+    return part.sync().state, moves
+
+
+@pytest.mark.parametrize("layout", [(0, 1), (1, 0)])
+def test_adj_keeps_the_programs_layout(tmp_path, monkeypatch, layout):
+    """After init, grows, a compaction, a rebalance, a restore, scan
+    tails and a ``place``, the session's ``adj`` is in ``adj_format`` and
+    ``metrics()["relayouts"]`` counts exactly the moves ``_pin`` made.
+    Row-major is the CPU's default, so under the real pin nothing moves;
+    a column-major pin, which the CPU also runs, makes each step outside
+    the programs move ``adj`` once, and leaves the state bit-identical."""
+    monkeypatch.setattr(part_api, "ADJ_LAYOUT",
+                        Layout(major_to_minor=layout))
+    state, moves = _lifecycle(layout, str(tmp_path / "a"))
+    if layout == (0, 1):
+        assert moves == [0] * 8
+        return
+    feed_grows, *rest = moves
+    assert feed_grows >= 1
+    # grow, compact, rebalance, snapshot, restore, tails, place
+    assert rest == [1, 1, 1, 0, 1, 0, 1]
+    monkeypatch.setattr(part_api, "ADJ_LAYOUT", Layout(major_to_minor=(0, 1)))
+    _identical(_lifecycle((0, 1), str(tmp_path / "b"))[0], state)

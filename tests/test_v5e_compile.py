@@ -25,9 +25,7 @@ from repro.core.state import init_state
 from repro.kernels.fused_chooser.fused_chooser import (
     EV_COLS, SCAL_N, fused_window_choose,
 )
-from repro.kernels.fused_chooser.ops import (
-    _run_window_mixed_fused, sweep_window_mixed_fused,
-)
+from repro.kernels.fused_chooser.ops import sweep_window_mixed_fused
 from repro.kernels.partition_affinity.partition_affinity import (
     partition_affinity,
 )
@@ -138,27 +136,58 @@ def test_sweep_kernel_lanes_compile(one_chip):
     assert "tpu_custom_call" in c.as_text()
 
 
+def _session_program(kind, sharding):
+    """The dense session's donated program ``kind`` on ``sharding``, as
+    ``Partitioner`` binds it, with its event arguments as shapes."""
+    i32 = functools.partial(_shape, dtype=jnp.int32, sharding=sharding)
+    fmt = part_api.adj_format(sharding)
+    ev = [i32((W,)), i32((W,)), i32((W, D)), i32(())]
+    if kind == "adds":
+        return part_api._adds_donated(fmt, "sdp", CFG), ev[1:]
+    if kind == "mixed_kernel":
+        return part_api._mixed_fused_donated(fmt, "sdp", CFG,
+                                             interpret=False), ev
+    return part_api._mixed_donated(fmt, "sdp", CFG), ev
+
+
 @pytest.mark.parametrize("use_kernel", [False, True])
 def test_dense_mixed_window_fits_one_chip(one_chip, use_kernel):
-    """The session's mixed-window program at n = 2**22 (about 3.2 GB of
+    """The session's mixed-window program at n = 2**22 (about 4.3 GB of
     state) fits one chip with its carried state donated."""
-    n = 1 << 22
-    st = _state_shapes(n, one_chip)
-    ev = [_shape((W,), jnp.int32, one_chip), _shape((W,), jnp.int32, one_chip),
-          _shape((W, D), jnp.int32, one_chip), _shape((), jnp.int32, one_chip)]
-    if use_kernel:
-        fn = jax.jit(_run_window_mixed_fused,
-                     static_argnames=("policy", "cfg", "interpret", "variant"),
-                     donate_argnums=(0,))
-        kw = {"interpret": False}
-    else:
-        fn, kw = part_api._mixed_donated, {}
-    c = fn.lower(st, *ev, policy="sdp", cfg=CFG, **kw).compile()
+    fn, ev = _session_program("mixed_kernel" if use_kernel else "mixed",
+                              one_chip)
+    c = fn.lower(_state_shapes(1 << 22, one_chip), *ev).compile()
     m = c.memory_analysis()
     assert m.alias_size_in_bytes >= 3e9, "the state was not donated"
     peak = m.argument_size_in_bytes + m.temp_size_in_bytes
     assert peak < HBM_BYTES, f"{peak / 1e9:.2f} GB does not fit one chip"
     assert ("tpu_custom_call" in c.as_text()) == use_kernel
+
+
+@pytest.mark.parametrize("kind", ["adds", "mixed", "mixed_kernel"])
+def test_session_program_keeps_adj_row_major(one_chip, kind):
+    """At n = 2**22 each dense session program takes and returns ``adj``
+    row-major, aliased in place: no copy of the whole adjacency on entry
+    or exit, and no second adjacency as temp."""
+    n = 1 << 22
+    fn, ev = _session_program(kind, one_chip)
+    c = fn.lower(_state_shapes(n, one_chip), *ev).compile()
+    assert c.input_formats[0][0].adj.layout.major_to_minor == (0, 1)
+    assert c.output_formats.adj.layout.major_to_minor == (0, 1)
+    assert not re.search(rf"s32\[{n},{D}\]\{{[^}}]*\}} copy\(", c.as_text())
+    m = c.memory_analysis()
+    assert m.temp_size_in_bytes < 64e6
+    assert m.alias_size_in_bytes >= 3e9, "the state was not donated"
+
+
+def test_session_init_builds_adj_row_major(one_chip):
+    """The dense session's initial state is built straight into the
+    programs' row-major ``adj``, with no temp."""
+    n = 1 << 22
+    init = part_api._init_pinned(n, D, K, 1, part_api.adj_format(one_chip))
+    c = init.lower(_shape((2,), jnp.uint32, one_chip)).compile()
+    assert c.output_formats.adj.layout.major_to_minor == (0, 1)
+    assert c.memory_analysis().temp_size_in_bytes == 0
 
 
 def test_sharded_window_compiles_on_four_chips(topo):
