@@ -80,7 +80,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax._src import config as jax_config
 from jax.experimental.layout import Format, Layout
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from repro.checkpoint.manager import CheckpointManager
 from repro.core import engine as eng
@@ -95,7 +95,7 @@ from repro.core.state import (
 )
 from repro.core.sharded_state import (
     gather_state, init_sharded_state, pad_rows, per_device_state_bytes,
-    shard_state, unshard_state,
+    shard_state, state_shardings, unshard_state,
 )
 from repro.core.transition import EventTrace
 from repro.core.metrics import load_imbalance, normalized_load_imbalance
@@ -118,13 +118,18 @@ _PATHS = ("scan", "adds", "mixed", "adds_kernel", "mixed_kernel", "sharded")
 # adjacency to row-major on entry and back on exit: a copy each way, and
 # a second adjacency as temp. So every session program pins ``adj`` to
 # ``adj_format`` on input and output, ``init_state`` builds it there, and
-# ``Partitioner._pin`` re-places a state any other step produced. On a CPU
-# row-major is the default and the pin changes nothing.
+# ``Partitioner._pin`` re-places a state any other step produced. A
+# vertex-sharded session keeps each chip's row block in the same layout:
+# its window needs none, but a row gather from a column-major block (the
+# benchmark's state check reads the touched rows) copies the whole block
+# to row-major first. On a CPU row-major is the default and the pin
+# changes nothing.
 ADJ_LAYOUT = Layout(major_to_minor=(0, 1))   # tiling left to the compiler
 
 
 def adj_format(sharding) -> Format:
-    """The device format of a dense session's ``adj`` on ``sharding``."""
+    """The device format of a session's ``adj`` on ``sharding``: one
+    device's whole block, or each chip's row block on a vertices mesh."""
     return Format(ADJ_LAYOUT, sharding)
 
 
@@ -426,13 +431,14 @@ class Partitioner:
         self._fmt = None
         if self._sharded:
             self._mesh = _resolve_vertices_mesh(shard_devices)
+            self._fmt = adj_format(state_shardings(self._mesh).adj)
             # semantic geometry: the tier a dense session would sit at —
             # what the knobs (LDG capacity) and checkpoints see; the
             # physical row count is padded to a multiple of the mesh
             self._sem_geom = Geometry(int(n or 1), int(max_deg or 1),
                                       cfg.k_max)
-            self._state = init_sharded_state(
-                *self._sem_geom, cfg.k_init, seed, self._mesh)
+            self._state = _uncached(init_sharded_state)(
+                *self._sem_geom, cfg.k_init, seed, self._mesh, ADJ_LAYOUT)
         else:
             self._bind(SingleDeviceSharding(jax.devices()[0]))
             self._state = _uncached(_init_pinned(
@@ -736,7 +742,8 @@ class Partitioner:
         self.sync()
         dense = unshard_state(self._state, n=self._sem_geom.n)
         self._mesh = _resolve_vertices_mesh(devices)
-        self._state = shard_state(dense, self._mesh)
+        self._fmt = adj_format(state_shardings(self._mesh).adj)
+        self._state = self._pin(dense)
         return self
 
     # -- external ids -------------------------------------------------------
@@ -937,15 +944,22 @@ class Partitioner:
         w = self.window
         if self._sharded:
             from repro.runtime.shard_session import sharded_stream_fn
-            fn = sharded_stream_fn(
+            fn = _uncached(sharded_stream_fn(
                 self._mesh, n_sem=self._sem_geom.n, policy=self.policy,
-                cfg=self.cfg, window=w, n_events=w)
+                cfg=self.cfg, window=w, n_events=w, adj_layout=ADJ_LAYOUT))
             with self._dispatch("sharded", len(et), w):
-                self._state = fn(
-                    self._state, wnd._pad_to(jnp.asarray(et), w, EVENT_PAD),
-                    wnd._pad_to(jnp.asarray(vx), w, -1),
-                    wnd._pad_to(jnp.asarray(nb), w, -1),
-                    jnp.int32(self._cursor))
+                # the window's event tensors, padded on the host, go to
+                # every device of the mesh in one transfer
+                pad = w - len(et)
+                events = (np.pad(et, (0, pad), constant_values=EVENT_PAD),
+                          np.pad(vx, (0, pad), constant_values=-1),
+                          np.pad(nb, ((0, pad), (0, 0)), constant_values=-1),
+                          np.int32(self._cursor))
+                with telemetry.span("session.shard_put",
+                                    bytes=sum(a.nbytes for a in events)):
+                    events = jax.device_put(
+                        events, NamedSharding(self._mesh, PartitionSpec()))
+                self._state = fn(self._state, *events)
             return
         adds = bool(np.all(et == EVENT_ADD))
         path = ("adds" if adds else "mixed") \
@@ -973,22 +987,31 @@ class Partitioner:
 
     def _pin(self, state: PartitionState) -> PartitionState:
         """Place a state that a step outside the session's programs made
-        (a regeometry, a repack, a rebalance, a restore, a ``place``)
-        where those programs take it: a sharded session's on its vertices
-        mesh; a dense session's with ``adj`` in ``adj_format``, moved
-        only where its format differs. Each such move is a
-        ``session.relayout`` span and counts in ``metrics()["relayouts"]``."""
-        if self._sharded:
-            return shard_state(state, self._mesh)
+        (a regeometry, a repack, a rebalance, a restore, a ``place``, a
+        ``reshard``) where those programs take it: ``adj`` in the format
+        ``self._fmt`` (row-major; on one device, or in row blocks on the
+        vertices mesh), moved only where its format differs. Each such
+        move is a ``session.relayout`` span and counts in
+        ``metrics()["relayouts"]``. A sharded state's other leaves go to
+        their mesh placements either way."""
         adj = state.adj
         if (adj.format.layout.major_to_minor
                 == self._fmt.layout.major_to_minor
                 and adj.sharding == self._fmt.sharding):
-            return state
+            return self._place(state) if self._sharded else state
         with telemetry.span("session.relayout", bytes=adj.nbytes):
-            adj = _uncached(jax.device_put)(adj, self._fmt, donate=True)
+            if self._sharded:
+                state = self._place(state)
+            else:
+                state = state._replace(adj=_uncached(jax.device_put)(
+                    adj, self._fmt, donate=True))
         self._relayouts += 1
-        return state._replace(adj=adj)
+        return state
+
+    def _place(self, state: PartitionState) -> PartitionState:
+        """``state`` on the vertices mesh, rows padded to a mesh multiple
+        and ``adj``'s blocks in ``ADJ_LAYOUT``."""
+        return _uncached(shard_state)(state, self._mesh, ADJ_LAYOUT)
 
     def sync(self) -> "Partitioner":
         """Block until every dispatched feed has executed (feeds are

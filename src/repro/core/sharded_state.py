@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import jax
 import numpy as np
+from jax.experimental.layout import Format, Layout
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.geometry import Geometry, geometry_of
@@ -59,14 +60,20 @@ def state_specs() -> PartitionState:
     )
 
 
-def state_shardings(mesh: jax.sharding.Mesh) -> PartitionState:
+def state_shardings(mesh: jax.sharding.Mesh,
+                    adj_layout: Layout | None = None) -> PartitionState:
     """`state_specs` bound to a mesh as a NamedSharding pytree (the
-    leaves are shardings, so this is safe to pass to `jax.device_put`)."""
-    return PartitionState(*(NamedSharding(mesh, s) for s in state_specs()))
+    leaves are shardings, so this is safe to pass to `jax.device_put`).
+    With ``adj_layout``, ``adj``'s leaf is a `Format` that also fixes the
+    device layout of each row block; without it the compiler picks."""
+    sh = PartitionState(*(NamedSharding(mesh, s) for s in state_specs()))
+    if adj_layout is None:
+        return sh
+    return sh._replace(adj=Format(adj_layout, sh.adj))
 
 
-def shard_state(state: PartitionState,
-                mesh: jax.sharding.Mesh) -> PartitionState:
+def shard_state(state: PartitionState, mesh: jax.sharding.Mesh,
+                adj_layout: Layout | None = None) -> PartitionState:
     """Place a (dense or differently-sharded) state on the vertices mesh,
     padding rows up to a multiple of the shard count first."""
     shards = n_shards(mesh)
@@ -74,11 +81,12 @@ def shard_state(state: PartitionState,
     target = pad_rows(g.n, shards)
     if target != g.n:
         state = grow_state(state, Geometry(target, g.max_deg, g.k_max))
-    return jax.device_put(state, state_shardings(mesh))
+    return jax.device_put(state, state_shardings(mesh, adj_layout))
 
 
 def init_sharded_state(n: int, max_deg: int, k_max: int, k_init: int,
-                       seed: int, mesh: jax.sharding.Mesh) -> PartitionState:
+                       seed: int, mesh: jax.sharding.Mesh,
+                       adj_layout: Layout | None = None) -> PartitionState:
     """``init_state`` built directly in its vertices-mesh layout, rows
     padded to a multiple of the shard count. Each device allocates only
     its own row blocks, so a state larger than one device's memory can
@@ -86,7 +94,7 @@ def init_sharded_state(n: int, max_deg: int, k_max: int, k_init: int,
     materialize the whole state on one device)."""
     n_pad = pad_rows(n, n_shards(mesh))
     build = jax.jit(init_state, static_argnums=(0, 1, 2, 3, 4),
-                    out_shardings=state_shardings(mesh))
+                    out_shardings=state_shardings(mesh, adj_layout))
     return build(n_pad, max_deg, k_max, k_init, seed)
 
 

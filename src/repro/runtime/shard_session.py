@@ -46,8 +46,9 @@ Round structure per window (W slots, D = max_deg, P shards):
     choice-independence trick the fused chooser's prep scan exploits).
   round 3 — `fused_window_choose_ref` over the assembled tables, with
     the *semantic* n (row padding must not perturb LDG's capacity knob).
-  round 4 — shard-local apply: scatter-max of touch slots per block,
-    then the journal rebuild `w_label[last_touch] / remap[label0]`.
+  round 4 — shard-local apply: every row takes its committed label
+    through the scale-in remap, then each vertex touched in the window
+    takes the label of its last touching slot (a W-row scatter).
 
 The O(K²) cut matrix, K-vector loads, and scalar counters ride the
 replicated carry. State between windows is GSPMD global arrays with the
@@ -62,13 +63,14 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.experimental.layout import Layout
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import transition as tx
 from repro.core.config import EngineConfig
 from repro.core.geometry import Geometry, resolve_geometry
 from repro.core.sharded_state import (
-    init_sharded_state, state_specs, unshard_state,
+    init_sharded_state, state_shardings, state_specs, unshard_state,
 )
 from repro.core.state import PartitionState
 from repro.graph.stream import (
@@ -114,6 +116,12 @@ def _sharded_window(state: PartitionState, ets, vs, rows, t0,
     # Mirrors ops._prepare_window op-for-op on this block, including the
     # self-loop aliasing order of the two DEL_EDGE row writes. All reads
     # of v/u rows are garbage off-owner; every consumer is owner-masked.
+    # Presence rides the scan as int32 (1 present), as the dense window
+    # carries its int32 label journal. Carried as bool, the per-slot
+    # writes to a 2**23-entry block went wrong on a v5e chip: the
+    # K-state left the reference's five windows into the churn, while
+    # the int32 carry stays exact (the same block of 2**20 rows was
+    # exact either way).
     def step(carry, i):
         adj, present = carry
         v = safe_vs[i]
@@ -125,7 +133,7 @@ def _sharded_window(state: PartitionState, ets, vs, rows, t0,
         o_v = owned(v)
         o_u = owned(safe_u)
 
-        pv = present[loc(v)]
+        pv = present[loc(v)] != 0
         fresh = add_i & ~pv
         was = dv_i & pv
         in_adj = jnp.any(own_row == u) & (u >= 0)
@@ -135,10 +143,11 @@ def _sharded_window(state: PartitionState, ets, vs, rows, t0,
             jnp.where(o_v, fresh.astype(i32), 0),
             jnp.where(o_v, was.astype(i32), 0),
             jnp.where(o_v, (de_i & pv & in_adj).astype(i32), 0),
-            jnp.where(o_u, present[loc(safe_u)].astype(i32), 0),
+            jnp.where(o_u, present[loc(safe_u)], 0),
         )
 
-        present = present.at[tgt(v, add_i | dv_i)].set(add_i, mode="drop")
+        present = present.at[tgt(v, add_i | dv_i)].set(
+            add_i.astype(i32), mode="drop")
 
         row_v_de = jnp.where((own_row == u) & (u >= 0), -1, own_row)
         w1_val = jnp.where(add_i, row, jnp.where(de_i, row_v_de, own_row))
@@ -149,7 +158,8 @@ def _sharded_window(state: PartitionState, ets, vs, rows, t0,
         return (adj, present), em
 
     (adj_loc, _), em = jax.lax.scan(
-        step, (state.adj, state.present), jnp.arange(w, dtype=i32))
+        step, (state.adj, state.present.astype(i32)),
+        jnp.arange(w, dtype=i32))
     with jax.named_scope("window.psum_emit"):
         rows_dv2, fresh_c, was_c, e1_c, e2_c = jax.lax.psum(em, AXIS)
     fresh = fresh_c != 0
@@ -208,12 +218,15 @@ def _sharded_window(state: PartitionState, ets, vs, rows, t0,
             autoscaling=policy == "sdp" and cfg.autoscale, dynamic=False)
 
     # ---- round 4: shard-local apply ---------------------------------
-    lt_loc = jnp.full((n_loc,), -1, i32)
-    lt_loc = lt_loc.at[tgt(safe_vs, touches)].max(iota, mode="drop")
-    lbl_touched = w_label[jnp.clip(lt_loc, 0, w - 1)]
+    # A slot is its vertex's last touch when no later slot touches it;
+    # those slots' vertices are distinct, so the scatter has one writer
+    # a row.
+    later_touch = before.T & touches[None, :] \
+        & (safe_vs[None, :] == safe_vs[:, None])
+    last = touches & ~jnp.any(later_touch, axis=1)
     lbl_kept = jnp.where(label0_loc >= 0,
                          remap[jnp.maximum(label0_loc, 0)], -1)
-    label_final = jnp.where(lt_loc >= 0, lbl_touched, lbl_kept)
+    label_final = lbl_kept.at[tgt(safe_vs, last)].set(w_label, mode="drop")
     return state._replace(
         assignment=label_final, present=label_final >= 0, adj=adj_loc,
         active=active != 0, edge_load=loads[0], vertex_count=loads[1],
@@ -225,14 +238,16 @@ def _sharded_window(state: PartitionState, ets, vs, rows, t0,
 @functools.lru_cache(maxsize=None)
 def sharded_stream_fn(mesh: jax.sharding.Mesh, *, n_sem: int, policy: str,
                       cfg: EngineConfig, window: int, n_events: int,
-                      donate: bool = True):
+                      donate: bool = True, adj_layout: Layout | None = None):
     """The jitted sharded step: ``fn(state, ets, vs, rows, t0) -> state``
     processing ``n_events`` (a multiple of ``window``) through a
     lax.scan of `_sharded_window` under one `shard_map`. ``state`` is a
     GSPMD global `PartitionState` with `sharded_state.state_specs`
-    shardings (donated when ``donate``); events are replicated. Cached
-    per (mesh, geometry-tier, policy, config, window, length) — the
-    sharded analogue of the dense session's per-tier re-jit."""
+    shardings (donated when ``donate``); events are replicated. With
+    ``adj_layout`` the step takes and returns each ``adj`` row block in
+    that device layout. Cached per (mesh, geometry-tier, policy, config,
+    window, length, layout) — the sharded analogue of the dense session's
+    per-tier re-jit."""
     if n_events % window != 0:
         raise ValueError(
             f"sharded_stream_fn(n_events={n_events}, window={window}): "
@@ -258,7 +273,9 @@ def sharded_stream_fn(mesh: jax.sharding.Mesh, *, n_sem: int, policy: str,
         body_stream, mesh=mesh,
         in_specs=(specs, P(), P(), P(), P()),
         out_specs=specs, check_vma=False)
-    return jax.jit(fn, donate_argnums=(0,) if donate else ())
+    st, rep = state_shardings(mesh, adj_layout), NamedSharding(mesh, P())
+    return jax.jit(fn, in_shardings=(st,) + (rep,) * 4, out_shardings=st,
+                   donate_argnums=(0,) if donate else ())
 
 
 def run_stream_sharded(

@@ -316,13 +316,16 @@ def test_pad_slots_of_a_known_chopping():
 
 # -- the layout the session keeps adj in --------------------------------------
 
-def _lifecycle(layout, directory):
+def _lifecycle(layout, directory, sharded=False):
     """Run a session through every step that makes its state outside its
     programs, with ``adj`` pinned to ``layout``; return the final state
     and, per step, the moves of ``adj`` its ``session.relayout`` spans
-    and ``metrics()["relayouts"]`` agree on."""
+    and ``metrics()["relayouts"]`` agree on. A ``sharded`` session runs
+    on a one-device vertices mesh and ends with a ``reshard`` where a
+    dense one ends with a ``place``."""
     s, cfg = _churn_fixture()
     mid = s.num_events // 2
+    mesh = {"sharded": True, "shard_devices": 1} if sharded else {}
 
     def feed(p, a, b, chunk):
         for t in range(a, b, chunk):
@@ -339,11 +342,12 @@ def _lifecycle(layout, directory):
         lambda p: p.compact(),
         lambda p: then(p, p.rebalance(m=8, passes=1)),
         lambda p: then(p, p.snapshot(directory)),
-        lambda p: Partitioner.restore(directory, cfg, window=32),
+        lambda p: Partitioner.restore(directory, cfg, window=32, **mesh),
         lambda p: feed(p, mid, s.num_events, 45),       # scan tails
-        lambda p: p.place(jax.devices()[0]),
+        lambda p: (p.reshard(devices=1) if sharded
+                   else p.place(jax.devices()[0])),
     ]
-    part = Partitioner(cfg, seed=0, window=32)
+    part = Partitioner(cfg, seed=0, window=32, **mesh)
     assert part.metrics()["relayouts"] == 0
     moves = []
     for fn in steps:
@@ -382,3 +386,26 @@ def test_adj_keeps_the_programs_layout(tmp_path, monkeypatch, layout):
     assert rest == [1, 1, 1, 0, 1, 0, 1]
     monkeypatch.setattr(part_api, "ADJ_LAYOUT", Layout(major_to_minor=(0, 1)))
     _identical(_lifecycle((0, 1), str(tmp_path / "b"))[0], state)
+
+
+@pytest.mark.parametrize("layout", [(0, 1), (1, 0)])
+def test_sharded_adj_keeps_the_programs_layout(tmp_path, monkeypatch,
+                                               layout):
+    """A vertex-sharded session keeps its ``adj`` blocks in the same
+    pinned layout through the same steps, a ``reshard`` in place of the
+    ``place``. Every step outside the window program leaves a state off
+    the mesh or in another format, so each moves ``adj`` once and counts
+    it, whichever layout is pinned; a feed's windows move nothing; and
+    the state is the same under either pin."""
+    monkeypatch.setattr(part_api, "ADJ_LAYOUT",
+                        Layout(major_to_minor=layout))
+    state, moves = _lifecycle(layout, str(tmp_path / "a"), sharded=True)
+    feed_grows, *rest = moves
+    assert feed_grows >= 1
+    # grow, compact, rebalance, snapshot, restore, tails, reshard
+    assert rest == [1, 1, 1, 0, 1, 0, 1]
+    if layout == (1, 0):
+        monkeypatch.setattr(part_api, "ADJ_LAYOUT",
+                            Layout(major_to_minor=(0, 1)))
+        _identical(_lifecycle((0, 1), str(tmp_path / "b"),
+                              sharded=True)[0], state)

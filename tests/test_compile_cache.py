@@ -63,8 +63,11 @@ partitioner.ADJ_LAYOUT = Layout(major_to_minor=(1, 0))
 g = make_graph("social", 90, 260, seed=2)
 s = gstream.interleaved_churn(g, warmup_frac=0.2, del_every=3,
                               edge_del_every=5, seed=4)
+mesh = {"sharded": True, "shard_devices": 1} if sys.argv[2:] == ["sharded"] \
+    else {}
 part = Partitioner.from_stream(s, EngineConfig(k_max=8, k_init=1,
-                                               max_cap=100), window=32)
+                                               max_cap=100), window=32,
+                               **mesh)
 part.feed(s).grow_to(n=2 * part.n)
 h = hashlib.sha256()
 for leaf in jax.tree_util.tree_leaves(part.state):
@@ -75,19 +78,32 @@ print(json.dumps({"layout": part.state.adj.format.layout.major_to_minor,
 """
 
 
-def test_pinned_session_programs_survive_a_warm_cache(tmp_path):
-    """A session whose programs pin ``adj`` to a non-default layout runs
-    the same from a cold and from a warm persistent cache: JAX restores a
-    cached executable without its pinned layouts, so those programs are
-    never written to it."""
+def _cold_then_warm(tmp_path, *args):
     env = {k: v for k, v in os.environ.items()
            if k != "JAX_COMPILATION_CACHE_DIR"}
     env.update(PYTHONPATH=str(REPO / "src"), JAX_PLATFORMS="cpu",
                JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
     runs = [json.loads(subprocess.run(
-        [sys.executable, "-c", PINNED, str(REPO)], env=env, check=True,
-        capture_output=True, text=True, timeout=300,
+        [sys.executable, "-c", PINNED, str(REPO), *args], env=env,
+        check=True, capture_output=True, text=True, timeout=300,
     ).stdout.strip().splitlines()[-1]) for _ in range(2)]
     assert any((tmp_path / "cache").iterdir()), "the cache was not used"
+    return runs
+
+
+def test_pinned_session_programs_survive_a_warm_cache(tmp_path):
+    """A session whose programs pin ``adj`` to a non-default layout runs
+    the same from a cold and from a warm persistent cache: JAX restores a
+    cached executable without its pinned layouts, so those programs are
+    never written to it."""
+    runs = _cold_then_warm(tmp_path)
+    assert runs[0] == runs[1]
+    assert runs[0]["layout"] == [1, 0] and runs[0]["relayouts"] == 1
+
+
+def test_pinned_sharded_session_programs_survive_a_warm_cache(tmp_path):
+    """The same for a vertex-sharded session: its initial state and its
+    window program pin the ``adj`` blocks, and stay out of the cache."""
+    runs = _cold_then_warm(tmp_path, "sharded")
     assert runs[0] == runs[1]
     assert runs[0]["layout"] == [1, 0] and runs[0]["relayouts"] == 1

@@ -125,3 +125,29 @@ def test_feed_and_service_record_their_layers_and_no_bench_names():
                      "serve.chunk"}
     assert not any(n.startswith("bench.") for n in names)
 
+
+
+def test_shard_put_spans_one_per_sharded_window_only():
+    """A vertex-sharded session places each window's event tensors on its
+    mesh in one ``session.shard_put`` span, inside that window's
+    ``session.dispatch``; a dense session records none."""
+    s, cfg = _churn_fixture()
+    for sharded in (True, False):
+        t0 = time.perf_counter()
+        part = Partitioner.from_stream(
+            s, cfg, seed=0, window=32, engine="windowed",
+            **({"sharded": True, "shard_devices": 1} if sharded else {}))
+        part.feed((s.etype, s.vertex, s.nbrs)).sync()
+        got = telemetry.spans(since=t0)
+        puts = _named(got, "session.shard_put")
+        dispatch = _named(got, "session.dispatch")
+        if not sharded:
+            assert puts == [] and dispatch
+            continue
+        windows = part.metrics()["windows"]["sharded"]
+        assert windows == -(-s.num_events // 32) == len(puts) == len(dispatch)
+        for p, d in zip(puts, dispatch):
+            assert p.parent == "session.dispatch"
+            assert d.attrs["path"] == "sharded"
+            assert d.start <= p.start <= p.end <= d.end
+            assert p.attrs["bytes"] == 4 * (32 + 32 + 32 * part.max_deg + 1)

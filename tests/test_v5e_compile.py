@@ -209,3 +209,100 @@ def test_sharded_window_compiles_on_four_chips(topo):
     per_chip = m.argument_size_in_bytes + m.temp_size_in_bytes
     assert 3e9 < per_chip < HBM_BYTES
     assert len(re.findall(r" all-reduce(?:-start)?\(", c.as_text())) == 2
+
+
+def _friendster_state(mesh):
+    """The ``friendster-32m`` session's state at n = 2**25 on ``mesh``, as
+    shapes: 2**23 rows a chip."""
+    return _state_shapes(1 << 25, type(state_specs())(
+        *(NamedSharding(mesh, s) for s in state_specs())))
+
+
+@pytest.fixture(scope="module")
+def friendster_window(topo):
+    """The vertex-sharded window at n = 2**25 on a mesh of the four
+    chips, with ``adj`` pinned as ``Partitioner`` pins it, compiled once."""
+    n = 1 << 25
+    mesh = Mesh(topo.devices, ("vertices",))
+    rep = NamedSharding(mesh, jax.sharding.PartitionSpec())
+    fn = sharded_stream_fn(mesh, n_sem=n, policy="sdp", cfg=CFG, window=W,
+                           n_events=W, adj_layout=part_api.ADJ_LAYOUT)
+    return fn.lower(_friendster_state(mesh), _shape((W,), jnp.int32, rep),
+                    _shape((W,), jnp.int32, rep),
+                    _shape((W, D), jnp.int32, rep),
+                    _shape((), jnp.int32, rep)).compile()
+
+
+def test_sharded_window_at_2_25_fits_four_chips(friendster_window):
+    """At n = 2**25 each chip holds its 2**23-row block, row-major (192
+    lanes padded to 256): 8.63 GB of arguments, aliased in place, and
+    some 2 MB of temp, which leaves 7 GB of the 15.75 GB a program may
+    use for the rest of a run (the benchmark's state check takes about
+    1.9 GB)."""
+    m = friendster_window.memory_analysis()
+    per_chip = m.argument_size_in_bytes + m.temp_size_in_bytes
+    assert 8.5e9 < m.argument_size_in_bytes < 8.7e9
+    assert m.alias_size_in_bytes >= 8.5e9, "the state was not donated"
+    assert HBM_BYTES - per_chip > 7e9
+
+
+def test_sharded_window_at_2_25_has_two_allreduces(friendster_window):
+    """A window's only communication is its two psums."""
+    text = friendster_window.as_text()
+    assert len(re.findall(r" all-reduce(?:-start)?\(", text)) == 2
+
+
+def test_sharded_window_at_2_25_keeps_adj_row_major(friendster_window):
+    """The sharded window takes and returns each chip's ``adj`` block
+    row-major and never copies it whole."""
+    c = friendster_window
+    assert c.input_formats[0][0].adj.layout.major_to_minor == (0, 1)
+    assert c.output_formats.adj.layout.major_to_minor == (0, 1)
+    assert not re.search(r"s32\[8388608,192\]\{[^}]*\} copy\(", c.as_text())
+
+
+def test_sharded_window_at_2_25_applies_labels_by_scatter(
+        friendster_window):
+    """The apply writes each touched vertex's label with a W-row scatter:
+    no gather runs over the 2**23 rows of a chip, and the window's temp
+    holds no row-sized buffer."""
+    text = friendster_window.as_text()
+    assert not re.search(r"s32\[8388608\]\{[^}]*\} gather\(", text)
+    assert re.search(r"s32\[8388608\]\{[^}]*\} scatter\(", text)
+    assert friendster_window.memory_analysis().temp_size_in_bytes < 16e6
+
+
+def test_sharded_window_at_2_25_writes_presence_as_int32(
+        friendster_window):
+    """The prep scan's per-slot presence writes go to an int32 block:
+    no single-element update of a 2**23-entry bool array, whose per-slot
+    writes put a v5e chip's K-state off the reference's."""
+    text = friendster_window.as_text()
+    assert not re.search(
+        r"pred\[8388608\]\{[^}]*\} dynamic-update-slice\(", text)
+    assert re.search(
+        r"s32\[8388608\]\{[^}]*\} dynamic-update-slice\(", text)
+
+
+def test_touched_row_gather_at_2_25_fits_beside_the_state(topo):
+    """The row gather a benchmark run's state check makes after the
+    window (``bench/harness.snapshot``: 2**20 touched rows and the count
+    of real entries) reads a row-major block where it lies, with no
+    all-gather and no copy of the block: about 1.9 GB of temp and output
+    beside the 8.6 GB state. From the TPU's default column-major block
+    the same gather copies the block first (9.7 GB of temp)."""
+    mesh = Mesh(topo.devices, ("vertices",))
+    st = _friendster_state(mesh)
+    rep = NamedSharding(mesh, jax.sharding.PartitionSpec())
+    gather = jax.jit(
+        lambda adj, i: (adj[i], jnp.sum(adj >= 0, dtype=jnp.int32)),
+        in_shardings=(part_api.adj_format(st.adj.sharding), rep))
+    c = gather.lower(st.adj, _shape((1 << 20,), jnp.int32, rep)).compile()
+    text = c.as_text()
+    assert not re.search(r" all-gather(?:-start)?\(", text)
+    assert not re.search(r"s32\[8388608,192\]\{[^}]*\} copy\(", text)
+    m = c.memory_analysis()
+    per_chip = m.argument_size_in_bytes + m.temp_size_in_bytes \
+        + m.output_size_in_bytes
+    assert m.temp_size_in_bytes + m.output_size_in_bytes < 2.5e9
+    assert per_chip < HBM_BYTES - 4e9
