@@ -4,7 +4,7 @@
     python scripts/mem_probe.py
 
 The session's mixed-window program (``Partitioner``'s donated
-``_run_window_mixed``, with ``adj`` pinned row-major) is compiled with
+``run_window_factored``, with ``adj`` pinned row-major) is compiled with
 its temp reported, while the runtime's ``peak_bytes_in_use`` after a
 session counts only buffers. This probe separates the readings, on one
 chip, in one process:

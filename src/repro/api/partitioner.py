@@ -28,12 +28,14 @@ Guarantees:
   the *next* ``feed()`` — copy (``np.asarray``) anything you want to
   keep, or use ``snapshot()``.
 * **Auto engine selection.** Per call, full windows of ``window`` events
-  ride the batched mixed-window kernel (``run_window_mixed``, or the
-  small-carry ``run_window_adds`` for pure-ADD windows) and small tails
-  ride the faithful per-event scan; both are bit-identical, so the
-  choice is pure throughput. ``engine="scan"``/``"windowed"`` pin one
-  backend (``collect_trace=True`` implies the scan, the only backend
-  that produces per-event traces).
+  ride the batched mixed-window program (the factored window of
+  ``repro.runtime.factored_window``, the body the vertex-sharded session
+  runs too, or the small-carry ``run_window_adds`` for pure-ADD windows)
+  and small tails ride the faithful per-event scan; both are
+  bit-identical, so the choice is pure throughput.
+  ``engine="scan"``/``"windowed"`` pin one backend
+  (``collect_trace=True`` implies the scan, the only backend that
+  produces per-event traces).
 * **Resumability.** ``snapshot()``/``Partitioner.restore()`` wrap
   ``repro.checkpoint`` (atomic renames, retention); checkpoints record
   their geometry in metadata, and checkpoints that predate
@@ -104,6 +106,7 @@ from repro.graph.stream import (
 )
 from repro.rebalance import rebalance_jit
 from repro.runtime import telemetry
+from repro.runtime.factored_window import run_window_factored
 
 _ENGINES = ("auto", "scan", "windowed")
 # the programs a feed dispatches, as ``metrics()["windows"]`` counts them:
@@ -172,7 +175,9 @@ def _adds_donated(fmt: Format, policy: str, cfg: EngineConfig,
 
 @functools.lru_cache(maxsize=None)
 def _mixed_donated(fmt: Format, policy: str, cfg: EngineConfig):
-    return _donated(wnd._run_window_mixed, fmt, 4, policy=policy, cfg=cfg)
+    """The mixed-window program: the factored body the vertex-sharded
+    step runs too, with the whole state as one row block."""
+    return _donated(run_window_factored, fmt, 4, policy=policy, cfg=cfg)
 
 
 @functools.lru_cache(maxsize=None)

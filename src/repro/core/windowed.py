@@ -23,7 +23,11 @@ Two window kernels exist:
   slice through the fixup scan (the fast path for insert-only streams);
 * ``run_window_mixed`` — arbitrary interleavings of ADD / DEL_VERTEX /
   DEL_EDGE processed entirely on device, scoring every slot from a dense
-  per-vertex label journal; the transition semantics come verbatim from
+  per-vertex label journal (the sessions run the factored form of the
+  same window instead, ``repro.runtime.factored_window``, whose slot
+  loop carries only K-sized state; this one stays as the sweeps' lane
+  body and the oracle the tests hold it to); the transition semantics
+  come verbatim from
   ``repro.core.transition`` (the single definition site shared with the
   faithful engine and the sweep runtime). ``sweep_window_mixed`` is the
   same kernel under the *traced* knob (lax.switch policy, per-lane

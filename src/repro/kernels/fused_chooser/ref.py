@@ -1,6 +1,7 @@
 """Oracle for the fused window chooser: the SAME slot step
 (`fused_chooser.make_slot_step`) driven by a plain lax.scan instead of the
-Pallas fori_loop, with no pallas_call anywhere. Used to triangulate
+Pallas fori_loop, with no pallas_call anywhere. It is the slot loop both
+sessions run (`repro.runtime.factored_window`), and it triangulates
 failures — kernel vs ref isolates Pallas lowering issues, ref vs the
 faithful `_window_mixed_lane` isolates touch-table prep issues."""
 from __future__ import annotations

@@ -6,9 +6,9 @@ Why this shape
 Event slots inside a window are sequentially dependent through the
 K-sized counters (every placement shifts the loads the next slot scores
 against), so the slot loop itself cannot be split across devices without
-changing results. What CAN be split is everything O(n). The fused
-chooser (PR 7) already factored the mixed window into exactly that
-split:
+changing results. What CAN be split is everything O(n). The factored
+window (`repro.runtime.factored_window`, the body the dense session runs
+too) splits the mixed window into exactly that:
 
     prep (O(n + W·D), choice-independent) → slot loop (O(W·K), tiny)
     → apply (O(n))
@@ -22,33 +22,9 @@ payloads (one all-reduce of per-window deltas instead of per event) and
 makes bit-identity to the dense engines structural: every device
 executes the same f32 ops in the same order on the same values.
 
-Round structure per window (W slots, D = max_deg, P shards):
-
-  round 1 — shard-local prep scan over W. Each device carries only its
-    (adj block, present block); per slot it applies the faithful
-    adjacency/presence writes localized to its block (drop-mode
-    scatters, preserving the dense scan's self-loop write order) and
-    emits owner-masked scalars: the deleted vertex's adjacency row,
-    freshness/presence bits, DEL_EDGE existence halves. Values are
-    encoded +2 (ids/labels live in {-1} ∪ [0, n)) so 0 is the psum
-    identity and exactly one owner contributes.
-  psum #1 — merges the emissions; every device now holds the same (W,)
-    scalars the dense `_prepare_window` scan produces.
-  round 2 — the (W, D) score-source row table is now replicated (ADD
-    rows come from the event stream, DEL_VERTEX rows from psum #1), so
-    each device contributes the committed labels of the entries it
-    owns, plus the label0[v]/label0[u] columns.
-  psum #2 — merges that one-hop halo gather. Touch tables need NO
-    communication: which earlier slot last relabeled a vertex is a pure
-    function of the (etype, vertex) event structure, so they are
-    recomputed replicatedly with O(W²·D) vectorized compares (W is the
-    window size — bounded and small; this is the same
-    choice-independence trick the fused chooser's prep scan exploits).
-  round 3 — `fused_window_choose_ref` over the assembled tables, with
-    the *semantic* n (row padding must not perturb LDG's capacity knob).
-  round 4 — shard-local apply: every row takes its committed label
-    through the scale-in remap, then each vertex touched in the window
-    takes the label of its last touching slot (a W-row scatter).
+Each device's block starts at row ``axis_index * n_loc``; psum #1 merges
+the prep scan's owner-masked emissions, psum #2 the one-hop gather of
+committed labels (see `factored_window` for the four rounds).
 
 The O(K²) cut matrix, K-vector loads, and scalar counters ride the
 replicated carry. State between windows is GSPMD global arrays with the
@@ -66,20 +42,15 @@ import jax.numpy as jnp
 from jax.experimental.layout import Layout
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.core import transition as tx
 from repro.core.config import EngineConfig
 from repro.core.geometry import Geometry, resolve_geometry
 from repro.core.sharded_state import (
     init_sharded_state, state_shardings, state_specs, unshard_state,
 )
 from repro.core.state import PartitionState
-from repro.graph.stream import (
-    EVENT_ADD, EVENT_DEL_EDGE, EVENT_DEL_VERTEX, EVENT_PAD,
-    VertexStream, normalize_rows, pad_stream,
-)
-from repro.kernels.fused_chooser import fused_chooser as fk
-from repro.kernels.fused_chooser.ref import fused_window_choose_ref
+from repro.graph.stream import VertexStream, normalize_rows, pad_stream
 from repro.launch.mesh import make_vertices_mesh
+from repro.runtime.factored_window import factored_window
 
 AXIS = "vertices"
 
@@ -88,151 +59,13 @@ def _sharded_window(state: PartitionState, ets, vs, rows, t0,
                     *, n_sem: int, policy: str, cfg: EngineConfig):
     """One mixed window, executing INSIDE shard_map: row leaves of
     ``state`` are this device's (n_loc, ...) block, everything else is
-    replicated. See the module docstring for the round structure."""
+    replicated. The rounds are `factored_window`'s, with this device's
+    row offset and a psum over the mesh as the merge."""
     n_loc = state.assignment.shape[0]
-    w = vs.shape[0]
-    k_max = state.edge_load.shape[0]
-    i32 = jnp.int32
-    lo = jax.lax.axis_index(AXIS).astype(i32) * n_loc
-
-    ets = jnp.where(vs >= 0, ets, EVENT_PAD)
-    is_add = ets == EVENT_ADD
-    is_dv = ets == EVENT_DEL_VERTEX
-    is_de = ets == EVENT_DEL_EDGE
-    safe_vs = jnp.where(vs >= 0, vs, 0)
-    rows_add = jnp.where(is_add[:, None], rows, -1)
-    label0_loc = jnp.where(state.present, state.assignment, -1)
-
-    def owned(g):
-        return (g >= lo) & (g < lo + n_loc)
-
-    def loc(g):                      # clamped local index (gathers)
-        return jnp.clip(g - lo, 0, n_loc - 1)
-
-    def tgt(g, cond):                # local scatter target, drop unowned
-        return jnp.where(cond & owned(g), g - lo, n_loc)
-
-    # ---- round 1: shard-local prep scan -----------------------------
-    # Mirrors ops._prepare_window op-for-op on this block, including the
-    # self-loop aliasing order of the two DEL_EDGE row writes. All reads
-    # of v/u rows are garbage off-owner; every consumer is owner-masked.
-    # Presence rides the scan as int32 (1 present), as the dense window
-    # carries its int32 label journal. Carried as bool, the per-slot
-    # writes to a 2**23-entry block went wrong on a v5e chip: the
-    # K-state left the reference's five windows into the churn, while
-    # the int32 carry stays exact (the same block of 2**20 rows was
-    # exact either way).
-    def step(carry, i):
-        adj, present = carry
-        v = safe_vs[i]
-        row = rows[i]
-        add_i, dv_i, de_i = is_add[i], is_dv[i], is_de[i]
-        own_row = adj[loc(v)]
-        u = row[0]
-        safe_u = jnp.maximum(u, 0)
-        o_v = owned(v)
-        o_u = owned(safe_u)
-
-        pv = present[loc(v)] != 0
-        fresh = add_i & ~pv
-        was = dv_i & pv
-        in_adj = jnp.any(own_row == u) & (u >= 0)
-
-        em = (
-            jnp.where(dv_i & o_v, own_row + 2, 0),              # dv row
-            jnp.where(o_v, fresh.astype(i32), 0),
-            jnp.where(o_v, was.astype(i32), 0),
-            jnp.where(o_v, (de_i & pv & in_adj).astype(i32), 0),
-            jnp.where(o_u, present[loc(safe_u)], 0),
-        )
-
-        present = present.at[tgt(v, add_i | dv_i)].set(
-            add_i.astype(i32), mode="drop")
-
-        row_v_de = jnp.where((own_row == u) & (u >= 0), -1, own_row)
-        w1_val = jnp.where(add_i, row, jnp.where(de_i, row_v_de, own_row))
-        adj = adj.at[tgt(v, fresh | de_i)].set(w1_val, mode="drop")
-        row_u = adj[loc(safe_u)]     # after write 1 (self-loop aliasing)
-        row_u_de = jnp.where((row_u == v) & (u >= 0), -1, row_u)
-        adj = adj.at[tgt(safe_u, de_i)].set(row_u_de, mode="drop")
-        return (adj, present), em
-
-    (adj_loc, _), em = jax.lax.scan(
-        step, (state.adj, state.present.astype(i32)),
-        jnp.arange(w, dtype=i32))
-    with jax.named_scope("window.psum_emit"):
-        rows_dv2, fresh_c, was_c, e1_c, e2_c = jax.lax.psum(em, AXIS)
-    fresh = fresh_c != 0
-    was = was_c != 0
-    exists = is_de & (e1_c != 0) & (e2_c != 0)
-    rows_dv = rows_dv2 - 2           # the deleted vertex's row, where is_dv
-
-    # ---- round 2: replicated source rows, one halo gather -----------
-    src_row = jnp.where(is_add[:, None], rows_add,
-                        jnp.where(is_dv[:, None], rows_dv, -1))
-    src_safe = jnp.maximum(src_row, 0)
-    us = jnp.maximum(rows[:, 0], 0)
-    contrib = (
-        jnp.where(owned(src_safe), label0_loc[loc(src_safe)] + 2, 0),
-        jnp.where(owned(safe_vs), label0_loc[loc(safe_vs)] + 2, 0),
-        jnp.where(owned(us), label0_loc[loc(us)] + 2, 0),
-    )
-    with jax.named_scope("window.psum_halo"):
-        sl2, l0v2, l0u2 = jax.lax.psum(contrib, AXIS)
-    src_lbl = jnp.where(src_row >= 0, sl2 - 2, -1)
-
-    # touch tables: replicated recompute. The dense scan reads
-    # last_touch[x] at slot i before slot i's own update lands, so the
-    # value is the last j < i with (ADD_j | DEL_VERTEX_j) and vs_j == x.
-    iota = jnp.arange(w, dtype=i32)
-    touches = is_add | is_dv
-    before = iota[None, :] < iota[:, None]                  # (W, W)
-
-    def last_touch_of(entries):      # (W, ...) ids -> (W, ...) slot idx
-        m = (entries[..., None] == safe_vs) & touches
-        m = m & before.reshape((w,) + (1,) * (entries.ndim - 1) + (w,))
-        return jnp.max(jnp.where(m, iota, -1), axis=-1)
-
-    touch = jnp.where(src_row >= 0, last_touch_of(src_safe), -1)
-    lt_v = last_touch_of(safe_vs)
-    lt_u = last_touch_of(us)
-
-    ev = jnp.stack([
-        ets, safe_vs, fresh.astype(i32), was.astype(i32),
-        exists.astype(i32), l0v2 - 2, lt_v, l0u2 - 2, lt_u], axis=1)
-
-    # ---- round 3: the replicated slot loop (the tested oracle) ------
-    kn = tx.make_knobs(cfg, n_sem)
-    knobs = jnp.stack([jnp.float32(x) for x in kn])
-    flags = jnp.array([0, 1], i32)
-    rand_tab = tx.rand_index_table(state.key, t0, w, k_max)
-    scalars = jnp.stack([
-        state.num_partitions, state.total_edges, state.cut_edges,
-        state.denied_scaleout, state.scale_events])
-    w_label, _psel, remap, active, loads, cut_matrix, scal = \
-        fused_window_choose_ref(
-            ev, src_lbl, touch, rand_tab,
-            state.active, state.edge_load, state.vertex_count,
-            state.cut_matrix, scalars, knobs, flags, n=n_sem,
-            policy=policy, balance_guard=cfg.balance_guard,
-            autoscaling=policy == "sdp" and cfg.autoscale, dynamic=False)
-
-    # ---- round 4: shard-local apply ---------------------------------
-    # A slot is its vertex's last touch when no later slot touches it;
-    # those slots' vertices are distinct, so the scatter has one writer
-    # a row.
-    later_touch = before.T & touches[None, :] \
-        & (safe_vs[None, :] == safe_vs[:, None])
-    last = touches & ~jnp.any(later_touch, axis=1)
-    lbl_kept = jnp.where(label0_loc >= 0,
-                         remap[jnp.maximum(label0_loc, 0)], -1)
-    label_final = lbl_kept.at[tgt(safe_vs, last)].set(w_label, mode="drop")
-    return state._replace(
-        assignment=label_final, present=label_final >= 0, adj=adj_loc,
-        active=active != 0, edge_load=loads[0], vertex_count=loads[1],
-        num_partitions=scal[fk.SCAL_NP], total_edges=scal[fk.SCAL_TOTAL],
-        cut_edges=scal[fk.SCAL_CUT], denied_scaleout=scal[fk.SCAL_DENIED],
-        scale_events=scal[fk.SCAL_SCALE], cut_matrix=cut_matrix)
+    lo = jax.lax.axis_index(AXIS).astype(jnp.int32) * n_loc
+    return factored_window(
+        state, ets, vs, rows, t0, n_sem=n_sem, policy=policy, cfg=cfg,
+        lo=lo, merge=functools.partial(jax.lax.psum, axis_name=AXIS))
 
 
 @functools.lru_cache(maxsize=None)

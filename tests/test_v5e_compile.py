@@ -180,6 +180,59 @@ def test_session_program_keeps_adj_row_major(one_chip, kind):
     assert m.alias_size_in_bytes >= 3e9, "the state was not donated"
 
 
+@pytest.fixture(scope="module")
+def dense_mixed_2_20(one_chip):
+    """The dense session's mixed-window program at n = 2**20 (the
+    ``livejournal-1m`` tier), as ``Partitioner`` binds it, compiled once."""
+    fn, ev = _session_program("mixed", one_chip)
+    return fn.lower(_state_shapes(1 << 20, one_chip), *ev).compile()
+
+
+def _loop_carries(text):
+    """The carry shape of every ``while`` in compiled HLO text."""
+    return re.findall(r"%while(?:\.\d+)? = \((.*?)\) while\(", text)
+
+
+def test_dense_mixed_window_at_2_20_runs_no_kernel(dense_mixed_2_20):
+    """The default mixed window is XLA: no Pallas call on the path."""
+    assert "tpu_custom_call" not in dense_mixed_2_20.as_text()
+
+
+def test_dense_mixed_window_at_2_20_keeps_adj_row_major(dense_mixed_2_20):
+    """The factored window takes and returns ``adj`` row-major, aliased in
+    place, and never copies the (2**20, 192) block."""
+    c = dense_mixed_2_20
+    assert c.input_formats[0][0].adj.layout.major_to_minor == (0, 1)
+    assert c.output_formats.adj.layout.major_to_minor == (0, 1)
+    assert not re.search(r"s32\[1048576,192\]\{[^}]*\} copy\(", c.as_text())
+    assert c.memory_analysis().alias_size_in_bytes >= 1e9
+
+
+def test_dense_mixed_window_at_2_20_temp(dense_mixed_2_20):
+    """Temp stays small: 2,257,920 B for the factored window (the
+    journal-carrying loop it replaced compiled to 1,516,544 B), well
+    under 4 MB beside about 1.08 GB of state."""
+    assert dense_mixed_2_20.memory_analysis().temp_size_in_bytes < 4e6
+
+
+def test_dense_mixed_window_at_2_20_slot_loop_carries_no_row_array(
+        dense_mixed_2_20):
+    """Only the prep scan carries row-sized state, ``adj`` and an int32
+    presence block; the slot loop (the loop carrying the (K, K) cut
+    matrix) carries nothing of 2**20 entries, and no conditional returns
+    a row-sized operand."""
+    text = dense_mixed_2_20.as_text()
+    carries = _loop_carries(text)
+    slot = [c for c in carries if f"s32[{K},{K}]" in c]
+    prep = [c for c in carries if "[1048576,192]" in c]
+    assert len(slot) == 1 and len(prep) == 1
+    assert "1048576" not in slot[0]
+    assert re.search(r"s32\[1048576\]", prep[0])
+    assert not re.search(r"pred\[1048576\]", prep[0])
+    conds = re.findall(r"= \((.*?)\) conditional\(", text)
+    assert not any("1048576" in c for c in conds)
+
+
 def test_session_init_builds_adj_row_major(one_chip):
     """The dense session's initial state is built straight into the
     programs' row-major ``adj``, with no temp."""
